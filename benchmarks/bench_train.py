@@ -279,4 +279,7 @@ if __name__ == "__main__":
     import sys
 
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main()

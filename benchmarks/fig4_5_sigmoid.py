@@ -56,4 +56,7 @@ def run(n: int = 20001, xmax: float = 8.0, verbose: bool = True) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     run()

@@ -1,8 +1,10 @@
-"""The paper's own WikiText-2 LSTM LM (Table III: 84.98M params) as an arch.
+"""The paper's own WikiText-2 LSTM LM (Table III) as an arch.
 
-embedding 33278x650-ish -> 2-layer LSTM(650) -> tied FC decoder. Sized to
-match the 84.98M parameter count with the standard AWD-style 2x650 setup at
-WikiText-2 vocab 33278: emb 33278*650 + 2 LSTM layers + decoder."""
+embedding 33278x1024 -> 2-layer LSTM(hidden 1024) -> decoder tied to the
+embedding. Table III's 84.98M parameters count an untied 33278x1024
+decoder (+ its bias): 2 * 33278*1024 + 2 * (8*1024^2 + 4*1024) + 33278
+~= 84.97M. Tied, as here, the model holds 33278*1024 + 2 * (8*1024^2 +
+4*1024) ~= 50.9M parameters (the table is padded to 33280 rows)."""
 from .base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -11,5 +13,6 @@ CONFIG = ArchConfig(
     rope="none", supports_long=True,  # O(1) recurrent state
     tie_embeddings=True,
     source="paper Table III (WikiText-2, 84.98M params)",
-    notes="2-layer LSTM hidden 1024, tied embeddings: 33278*1024*2 + 2*8*1024^2 ~= 85M.",
+    notes="2-layer LSTM hidden 1024, tied embeddings: 33278*1024 + "
+          "2*(8*1024^2 + 4*1024) ~= 50.9M (84.97M with an untied decoder).",
 )

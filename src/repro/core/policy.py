@@ -53,6 +53,12 @@ class Policy:
     compute_dtype: str = "fp32"  # dtype matmuls execute in
     param_dtype: str = "fp32"  # dtype quantized weights are materialized in
     loss_scale: float = 1.0
+    # the train step sums the per-device f32 gradients itself (shard_map
+    # over a "data" mesh axis) and rounds the sum once, in its FP8 sweep:
+    # weight sites then emit raw f32 dW, neither snapped to FP8 at the LSTM
+    # kernels' flush nor narrowed to bf16 for an all-reduce the SPMD
+    # partitioner would insert at the dot
+    explicit_grad_sum: bool = False
 
     # -- dtype accessors -------------------------------------------------
     def cdt(self):

@@ -10,14 +10,16 @@ Resolution per call site weighs three things:
     argument; precedence: argument > context > env; default ``auto``.
   * **platform** — Pallas runs compiled on TPU and in ``interpret=True``
     validation mode everywhere else (``REPRO_KERNEL_INTERPRET=0|1``
-    overrides). ``auto`` therefore resolves to ``ref`` off-TPU — the
-    interpreter is a correctness tool, not a fast path — and ``pallas`` on
-    TPU. ``backend="pallas"`` forces the kernel path anywhere (interpreted
-    off-TPU), which is how the parity suite exercises it.
+    overrides off a TPU; ``=1`` is refused on one). ``auto`` therefore
+    resolves to ``ref`` off-TPU — the interpreter is a correctness tool,
+    not a fast path — and ``pallas`` on TPU. ``backend="pallas"`` forces
+    the kernel path anywhere (interpreted off-TPU), which is how the
+    parity suite exercises it.
   * **shape divisibility** — inputs the tiling doesn't divide are padded up
     to tile multiples (zero activations x zero-code weights contribute an
-    exact 0.0) when the padded work stays under ``PAD_WASTE_MAX`` x the
-    exact work, instead of silently falling back to the oracle.
+    exact 0.0). On a TPU the oracle runs only when asked for (``ref``):
+    an op with no padded path raises there (:class:`OracleRefused`)
+    rather than run the oracle in the kernel's place.
 
 Every resolution is recorded in ``STATS``: per-``(op, backend)`` counters
 plus the last ``Decision`` per op. Tests assert on these, so a tiling
@@ -77,22 +79,17 @@ from .rwkv_wkv.ops import wkv as wkv_op
 from .rwkv_wkv.ref import wkv_ref
 
 __all__ = [
-    "BACKENDS", "PAD_WASTE_MAX", "PackedTensor", "PackedTensor4", "Decision",
+    "BACKENDS", "PackedTensor", "PackedTensor4", "Decision",
     "DispatchStats", "STATS", "LEDGER", "record", "backend_policy",
     "use_backend", "interpret_mode", "matmul", "matmul4", "lstm_cell",
     "quantize", "qsigmoid", "packed_einsum", "hoist_packed", "matmul_tiles",
     "lstm_tiles", "row_tile", "matmul_dx", "matmul_dw", "lstm_cell_grad",
     "train_matmul", "lstm_cell_train", "pack_train", "hoist_train",
     "inference_only", "is_packed", "is_packed4", "pack4", "unpack4",
-    "rwkv_wkv", "flash_attention", "OpSpec", "REGISTRY",
+    "rwkv_wkv", "flash_attention", "OpSpec", "REGISTRY", "OracleRefused",
 ]
 
 BACKENDS = ("ref", "pallas", "auto")
-
-# auto mode pads to tile multiples only while padded_work / exact_work stays
-# under this; beyond it the oracle is the better deal (forced pallas always
-# pads).
-PAD_WASTE_MAX = 2.0
 
 # uint8 code that decodes to exactly 0.0 at any bias: e=0, mantissa index of
 # 0.0 in the symmetric 31-entry grid.
@@ -213,6 +210,9 @@ class DispatchStats:
 
     def __init__(self):
         self.counts: collections.Counter = collections.Counter()
+        # per-op resolutions that run the Pallas interpreter (a chip run
+        # must show none)
+        self.interpreted: collections.Counter = collections.Counter()
         self.last: dict[str, Decision] = {}
         self.costs: dict[tuple[str, str], costmodel.Cost] = {}
         self.touched: collections.Counter = collections.Counter()
@@ -223,6 +223,8 @@ class DispatchStats:
         with self._lock:
             key = (d.op, d.backend)
             self.counts[key] += 1
+            if d.interpret:
+                self.interpreted[d.op] += 1
             self.last[d.op] = d
             if d.cost is not None:
                 self.costs[key] = costmodel.merge_costs(
@@ -253,6 +255,7 @@ class DispatchStats:
     def reset(self) -> None:
         with self._lock:
             self.counts.clear()
+            self.interpreted.clear()
             self.last.clear()
             self.costs.clear()
             self.touched.clear()
@@ -339,13 +342,30 @@ def use_backend(name: str):
         _OVERRIDE.pop()
 
 
+class OracleRefused(RuntimeError):
+    """A TPU run would have put the jnp oracle or the Pallas interpreter in
+    a kernel's place without being asked to (``backend="ref"`` asks)."""
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def interpret_mode() -> bool:
     """Pallas execution mode for this process: compiled on TPU, interpreted
-    elsewhere. REPRO_KERNEL_INTERPRET=0|1 overrides."""
+    elsewhere. REPRO_KERNEL_INTERPRET=0 compiles anywhere (the compile
+    rehearsal); =1 interprets, and is refused on a TPU, where an
+    interpreted kernel would run in the compiled one's place."""
     env = os.environ.get("REPRO_KERNEL_INTERPRET")
+    if env is not None and env != "0":
+        if _on_tpu():
+            raise OracleRefused(
+                "REPRO_KERNEL_INTERPRET=1 on a TPU: the kernels run compiled "
+                "there; unset it")
+        return True
     if env is not None:
-        return env != "0"
-    return jax.default_backend() != "tpu"
+        return False
+    return not _on_tpu()
 
 
 def _decide(op: str, native: bool, waste: float, backend: str | None) -> Decision:
@@ -368,14 +388,8 @@ def _decide(op: str, native: bool, waste: float, backend: str | None) -> Decisio
         )
     if native:
         return Decision(op, "pallas", False, False, "auto:tpu, native tiles")
-    if waste <= PAD_WASTE_MAX:
-        return Decision(
-            op, "pallas", False, True,
-            f"auto:tpu, padded ({waste:.2f}x <= {PAD_WASTE_MAX}x)",
-        )
     return Decision(
-        op, "ref", False, False,
-        f"auto:padding waste {waste:.2f}x > {PAD_WASTE_MAX}x",
+        op, "pallas", False, True, f"auto:tpu, padded ({waste:.2f}x work)"
     )
 
 
@@ -499,13 +513,16 @@ def matmul(x, codes, bias, *, out_dtype=jnp.float32, precise: bool = True,
 
 
 def matmul4(x, w4: PackedTensor4, *, out_dtype=jnp.float32,
-            precise: bool = True, compute_dtype=None,
+            precise: bool = True, compute_dtype=None, transpose: bool = False,
             backend: str | None = None):
     """x [..., K] @ decode4(w4 [K, N]) -> [..., N], backend-resolved.
 
     The FloatSD4 sibling of :func:`matmul`: the weight operand is a
     :class:`PackedTensor4` (nibble-packed codes + group exponents), so the
     pallas path streams ~half the weight bytes of the FloatSD8 kernel.
+    ``transpose``: w4 is stored [N, K] (packed along N, the tied logits
+    head) and the product is x @ decode4(w4)^T; the kernel contracts the
+    code tile's second axis, so the nibbles stream as stored.
     Padding uses the zero-code convention: code columns/rows pad with
     ``ZERO_BYTE4`` (both nibbles the zero code) and exponent rows with 0 —
     both decode to exact 0.0, so padded lanes contribute nothing.
@@ -513,8 +530,12 @@ def matmul4(x, w4: PackedTensor4, *, out_dtype=jnp.float32,
     if compute_dtype is None:
         compute_dtype = jnp.float32 if precise else jnp.bfloat16
     k = x.shape[-1]
-    assert w4.k == k, (x.shape, w4.shape)
-    n = w4.codes.shape[1]
+    if transpose:
+        assert w4.codes.shape[1] == k, (x.shape, w4.shape)
+        n = w4.k
+    else:
+        assert w4.k == k, (x.shape, w4.shape)
+        n = w4.codes.shape[1]
     lead = x.shape[:-1]
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
@@ -523,7 +544,12 @@ def matmul4(x, w4: PackedTensor4, *, out_dtype=jnp.float32,
     x_bytes = jnp.dtype(x.dtype).itemsize
     o_bytes = jnp.dtype(out_dtype).itemsize
     if dec.backend == "ref":
-        y = floatsd4_matmul_ref(x2, w4.codes, w4.exps, k, out_dtype)
+        if transpose:
+            wq = floatsd4.decode_packed(w4.codes, w4.exps, w4.k)
+            y = jnp.dot(x2.astype(jnp.float32), wq.T,
+                        preferred_element_type=jnp.float32).astype(out_dtype)
+        else:
+            y = floatsd4_matmul_ref(x2, w4.codes, w4.exps, k, out_dtype)
         cost = fm4_cost.matmul4_fwd_cost(
             m, k, n, backend="ref", x_bytes=x_bytes, out_bytes=o_bytes,
         )
@@ -532,18 +558,23 @@ def matmul4(x, w4: PackedTensor4, *, out_dtype=jnp.float32,
     else:
         xx, cc, ee = x2, w4.codes, w4.exps
         if dec.padded:
+            # (packed rows, columns) of the padded code / exponent arrays
+            pr, pc = (np_, kp) if transpose else (kp, np_)
             xx = jnp.pad(x2, ((0, mp - m), (0, kp - k)))
             cc = jnp.pad(
-                cc, ((0, kp // 2 - cc.shape[0]), (0, np_ - n)),
+                cc, ((0, pr // 2 - cc.shape[0]), (0, pc - cc.shape[1])),
                 constant_values=ZERO_BYTE4,
             )
             ee = jnp.pad(
-                ee, ((0, kp // floatsd4.GROUP - ee.shape[0]), (0, np_ - n))
+                ee, ((0, pr // floatsd4.GROUP - ee.shape[0]),
+                     (0, pc - ee.shape[1]))
             )
-        bm, bn, bk = matmul_tiles(xx.shape[0], cc.shape[1], xx.shape[1])
+        bm, bn, bk = matmul_tiles(xx.shape[0], np_ if dec.padded else n,
+                                  xx.shape[1])
         y = floatsd4_matmul_pallas(
             xx, cc, ee, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
-            compute_dtype=compute_dtype, interpret=dec.interpret,
+            compute_dtype=compute_dtype, transpose_w=transpose,
+            interpret=dec.interpret,
         )
         cost = fm4_cost.matmul4_fwd_cost(
             m, k, n, backend="pallas", x_bytes=x_bytes, out_bytes=o_bytes,
@@ -855,23 +886,27 @@ def _decide_fallback(op: str, native: bool, why: str,
                      backend: str | None) -> Decision:
     """Resolution for ops without a padding path: pallas only when the
     tiling divides natively, ref otherwise — with the fallback reason
-    recorded so a shape regression shows up in STATS, not in silence."""
+    recorded so a shape regression shows up in STATS, not in silence.
+    On a TPU that fallback raises :class:`OracleRefused` instead."""
     pol = backend_policy(backend)
     interp = interpret_mode()
     if pol == "ref":
         return Decision(op, "ref", False, False, "policy:ref")
-    if pol == "pallas":
+    if pol == "pallas" and native:
+        return Decision(op, "pallas", interp, False, "policy:pallas")
+    if pol == "auto":
+        if interp:
+            return Decision(op, "ref", False, False,
+                            "auto:off-tpu (interpret is validation-only)")
         if native:
-            return Decision(op, "pallas", interp, False, "policy:pallas")
-        return Decision(op, "ref", False, False,
-                        f"policy:pallas, but {why} -> ref oracle (no padded path)")
-    if interp:
-        return Decision(op, "ref", False, False,
-                        "auto:off-tpu (interpret is validation-only)")
-    if native:
-        return Decision(op, "pallas", False, False, "auto:tpu, native tiles")
+            return Decision(op, "pallas", False, False, "auto:tpu, native tiles")
+    if _on_tpu():
+        raise OracleRefused(
+            f"{op}: {why} and the kernel has no padded path; pass "
+            f"backend='ref' to run the jnp oracle on the TPU")
+    reason = f"policy:pallas, but {why}" if pol == "pallas" else f"auto:{why}"
     return Decision(op, "ref", False, False,
-                    f"auto:{why} -> ref oracle (no padded path)")
+                    f"{reason} -> ref oracle (no padded path)")
 
 
 def rwkv_wkv(r, k, v, w, u, *, chunk: int = 16, backend: str | None = None):
@@ -1135,9 +1170,8 @@ def packed_einsum(eq: str, x, packed, *, out_dtype=jnp.float32,
     ref path decodes and einsums (bit-identical to the old unpack-then-
     einsum serving step); the pallas path feeds the codes to the fused
     decode+matmul kernel, transposing the (1-byte) codes when w is stored
-    [free, contract]. FloatSD4 codes are nibble-packed along axis 0 and
-    cannot be transposed at byte granularity, so the transposed layout
-    decodes + einsums on every backend (recorded, never silent).
+    [free, contract]. FloatSD4 codes are nibble-packed along axis 0, so
+    the FloatSD4 kernel takes that layout as stored (``transpose_w``).
     """
     ins, out = eq.replace(" ", "").split("->")
     xl, wl = ins.split(",")
@@ -1187,19 +1221,12 @@ def _packed4_einsum(eq: str, x, packed: PackedTensor4, transpose: bool, *,
                     out_dtype, cast_dtype, backend):
     """FloatSD4 arm of :func:`packed_einsum` (eq already validated)."""
     dec_backend = backend_policy(backend)
-    ref_policy = dec_backend == "ref" or (
-        dec_backend == "auto" and interpret_mode()
-    )
-    if ref_policy or transpose:
+    if dec_backend == "ref" or (dec_backend == "auto" and interpret_mode()):
         c = x.shape[-1]
         n_free = packed.shape[0 if transpose else 1]
-        reason = (
-            f"policy:{dec_backend} (packed4 einsum)" if ref_policy
-            else "packed4 transpose: nibble stream not byte-transposable, "
-                 "decode+einsum"
-        )
         record(
-            "floatsd4_matmul", "ref", reason=reason,
+            "floatsd4_matmul", "ref",
+            reason=f"policy:{dec_backend} (packed4 einsum)",
             cost=fm4_cost.matmul4_fwd_cost(
                 x.size // max(c, 1), c, n_free, backend="ref",
                 x_bytes=jnp.dtype(x.dtype).itemsize,
@@ -1219,7 +1246,8 @@ def _packed4_einsum(eq: str, x, packed: PackedTensor4, transpose: bool, *,
         return inference_only(y)
     cd = None if cast_dtype in (None, jnp.float32) else cast_dtype
     return inference_only(matmul4(
-        x, packed, out_dtype=out_dtype, compute_dtype=cd, backend=backend,
+        x, packed, out_dtype=out_dtype, compute_dtype=cd,
+        transpose=transpose, backend=backend,
     ))
 
 
@@ -1234,9 +1262,9 @@ def hoist_packed(w, *, m: int | None = None, dtype=None,
     less HBM weight traffic per step). Non-packed inputs pass through.
 
     ``m`` is the batch rows the scan-body matmuls will see; with it the
-    prediction runs the SAME geometry rule as ``matmul`` (including the
-    auto-mode padding-waste fallback), so a call site that would fall back
-    to ref can never be left packed and pay a full decode per time step.
+    prediction runs the SAME resolution as ``matmul``, so a call site that
+    resolves to ref can never be left packed and pay a full decode per
+    time step.
     """
     if not (is_packed(w) or is_packed4(w)):
         return w

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...core.fp8 import FP8_E5M2, quantize_fp8
+from .. import mosaic
 from .kernel import floatsd_matmul_pallas
 from .ref import floatsd_matmul_ref
 
@@ -82,7 +83,7 @@ def matmul_dw_kernel(xt_ref, g_ref, out_ref, acc_ref, *, n_k: int, quant: bool):
 
     xt = xt_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
-    acc_ref[...] += jnp.dot(xt, g, preferred_element_type=jnp.float32)
+    acc_ref[...] += mosaic.mxu_dot(xt, g)
 
     @pl.when(m_step == n_k - 1)
     def _flush():
@@ -92,12 +93,6 @@ def matmul_dw_kernel(xt_ref, g_ref, out_ref, acc_ref, *, n_k: int, quant: bool):
             acc = jnp.clip(acc, -57344.0, 57344.0)
             acc = acc.astype(jnp.float8_e5m2).astype(jnp.float32)
         out_ref[...] = acc.astype(out_ref.dtype)
-
-
-def _vmem_scratch(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
 
 
 @functools.partial(
@@ -116,7 +111,7 @@ def matmul_dw_pallas(
     m, k = x.shape
     m2, n = g.shape
     assert m == m2, (x.shape, g.shape)
-    xt = x.T  # [K, M]
+    xt = mosaic.widen(x).T  # [K, M]
     bm, bn, bk = min(bm, k), min(bn, n), min(bk, m)
     assert k % bm == 0 and n % bn == 0 and m % bk == 0, (k, n, m, bm, bn, bk)
     n_k = m // bk
@@ -131,6 +126,6 @@ def matmul_dw_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32),
-        scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
+        scratch_shapes=[mosaic.vmem_scratch((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(xt, g)
+    )(xt, mosaic.widen(g))

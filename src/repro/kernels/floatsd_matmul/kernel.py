@@ -19,29 +19,40 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from ...core import floatsd
+from .. import mosaic
 
-__all__ = ["floatsd_matmul_kernel", "floatsd_matmul_pallas"]
+__all__ = ["floatsd_matmul_kernel", "floatsd_matmul_pallas", "decode_tile"]
 
-# 32-entry mantissa LUT (index 31 unused -> 0)
-_LUT = np.zeros(32, np.float32)
-_LUT[:31] = floatsd.MANTISSA_VALUES
+
+def decode_tile(codes: jax.Array, bias: jax.Array) -> jax.Array:
+    """uint8 FloatSD8 codes -> f32 values, bit-identical to
+    ``core.floatsd.decode`` (``bias`` already clamped) without a table.
+
+    The 31 sorted mantissas are symmetric around index 15 (value 0), and
+    the positive half in quarters is 1..10 followed by 14..18 (the grid's
+    2.5 -> 3.5 hole), so ``|mantissa| = (a + 3 * (a > 10)) / 4`` with
+    ``a = |idx - 15|``. Index 31 is clipped to 30, as the oracle does.
+    """
+    c = codes.astype(jnp.int32)
+    d = jnp.minimum(c & 0x1F, 30) - 15
+    a = jnp.abs(d)
+    q = a + 3 * (a > 10).astype(jnp.int32)
+    mant = jnp.where(d < 0, -q, q).astype(jnp.float32) * 0.25
+    return mant * mosaic.pow2((c >> 5) + bias)
 
 
 def floatsd_matmul_kernel(
-    x_ref, codes_ref, bias_ref, lut_ref, out_ref, acc_ref, *, n_k: int,
+    x_ref, codes_ref, bias_ref, out_ref, acc_ref, *, n_k: int,
     compute_dtype=jnp.bfloat16,
 ):
     """One (bm x bn) output tile; accumulates over the K grid axis.
 
     x_ref:     [bm, bk]  activation tile (fp8/bf16/f32 storage)
     codes_ref: [bk, bn]  uint8 FloatSD8 codes
-    bias_ref:  [1, 1]    int32 per-tensor exponent bias
-    lut_ref:   [1, 32]   f32 mantissa LUT (pallas kernels take constants
-                         as inputs)
+    bias_ref:  [1, 1]    int32 per-tensor exponent bias (clamped)
     acc_ref:   [bm, bn]  f32 VMEM accumulator scratch
 
     ``compute_dtype`` is the MXU issue dtype: bf16 (default, full MXU rate)
@@ -53,25 +64,15 @@ def floatsd_matmul_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = codes_ref[...].astype(jnp.int32)
-    m_idx = codes & 0x1F
-    e = (codes >> 5).astype(jnp.float32)
-    mant = jnp.take(lut_ref[0, :], m_idx)  # VPU gather, 32-entry table
-    scale = jnp.exp2(e + bias_ref[0, 0].astype(jnp.float32))
-    w = (mant * scale).astype(compute_dtype)  # decoded tile stays in VMEM
+    # decoded tile stays in VMEM
+    w = decode_tile(codes_ref[...], bias_ref[0, 0]).astype(compute_dtype)
 
     x = x_ref[...].astype(compute_dtype)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    acc_ref[...] += mosaic.mxu_dot(x, w)
 
     @pl.when(k_step == n_k - 1)
     def _flush():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
-
-
-def _vmem_scratch(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
 
 
 @functools.partial(
@@ -107,11 +108,10 @@ def floatsd_matmul_pallas(
             pl.BlockSpec((bm, bk), lambda i, j, s: (i, s)),
             pl.BlockSpec((bk, bn), lambda i, j, s: (s, j)),
             pl.BlockSpec((1, 1), lambda i, j, s: (0, 0)),
-            pl.BlockSpec((1, 32), lambda i, j, s: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((m, n), mosaic.io_dtype(out_dtype)),
+        scratch_shapes=[mosaic.vmem_scratch((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, codes, jnp.reshape(bias.astype(jnp.int32), (1, 1)),
-      jnp.asarray(_LUT).reshape(1, 32))
+    )(mosaic.widen(x), codes,
+      jnp.reshape(floatsd._clamp_bias(bias), (1, 1))).astype(out_dtype)

@@ -2,12 +2,12 @@
 
 Runs after every optimizer step (paper §III-B: 'the master copy weights are
 then quantized to FloatSD8 for the next iteration'). Pure VPU work:
-nearest-grid-value rounding implemented as a broadcast compare-count against
-the 64 grid midpoints (no searchsorted on TPU), then a gather of the
-precomputed (exponent, mantissa-index) pair for the winning grid slot.
+nearest-grid-value rounding against the 64 grid midpoints, done as a select
+chain over compile-time constants (``mosaic.select_lut``: no searchsorted
+and no table gather on TPU) that directly yields the precomputed code of
+the winning grid slot, for each sign.
 
-Tiles are [bm, bn] VMEM blocks of the (flattened-2D) weight; the three LUT
-rows (midpoints / exponent / mantissa-idx) ride along as tiny inputs.
+Tiles are [bm, bn] VMEM blocks of the (flattened-2D) weight.
 """
 from __future__ import annotations
 
@@ -19,6 +19,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ...core import floatsd
+from .. import mosaic
 
 __all__ = ["quantize_kernel", "quantize_pallas"]
 
@@ -26,23 +27,21 @@ _GRID = floatsd._GRID_POS.astype(np.float32)  # 65 non-negative values
 _MID = ((_GRID[1:] + _GRID[:-1]) / 2).astype(np.float32)  # 64 midpoints
 _E = floatsd._GRID_E.astype(np.int32)
 _MIDX = floatsd._GRID_MIDX.astype(np.int32)
-_NG = _GRID.size  # 65
+# per grid slot: the code for +value in the low byte, for -value in the next
+# (``core.floatsd.encode``: code = e << 5 | mantissa index, sign folded into
+# the index as 30 - idx)
+_CODES = ((_E << 5) | _MIDX) | (((_E << 5) | (30 - _MIDX)) << 8)
 
 
-def quantize_kernel(x_ref, bias_ref, mid_ref, e_ref, midx_ref, out_ref):
+def quantize_kernel(x_ref, inv_scale_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)
-    scale = jnp.exp2(-bias_ref[0, 0].astype(jnp.float32))
-    n = jnp.abs(x) * scale
+    # |x| / 2^bias as a product with the exact power of two 2^-bias
+    n = jnp.abs(x) * inv_scale_ref[0, 0]
     n = jnp.minimum(n, _GRID[-1])
-    # nearest-grid index: count midpoints below n (broadcast compare-sum)
-    mids = mid_ref[0, :]  # [64]
-    gidx = jnp.sum(
-        (n[..., None] > mids[None, None, :]).astype(jnp.int32), axis=-1
-    )  # [bm, bn] in [0, 64]
-    e = jnp.take(e_ref[0, :], gidx)
-    midx = jnp.take(midx_ref[0, :], gidx)
-    midx_signed = jnp.where(x < 0, 30 - midx, midx)
-    out_ref[...] = ((e << 5) | midx_signed).astype(jnp.uint8)
+    # nearest grid slot = #(midpoints below n), resolved to its code pair
+    pair = mosaic.select_lut(n, _MID, _CODES)
+    code = jnp.where(x < 0, pair >> 8, pair & 0xFF)
+    out_ref[...] = code.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -57,17 +56,9 @@ def quantize_pallas(x, bias, *, bm: int = 256, bn: int = 256, interpret: bool = 
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 64), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, _NG), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, _NG), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.uint8),
         interpret=interpret,
-    )(
-        x,
-        jnp.reshape(bias.astype(jnp.int32), (1, 1)),
-        jnp.asarray(_MID).reshape(1, -1),
-        jnp.asarray(_E).reshape(1, -1),
-        jnp.asarray(_MIDX).reshape(1, -1),
-    )
+    )(mosaic.widen(x),
+      jnp.reshape(floatsd.exp2i(-floatsd._clamp_bias(bias)), (1, 1)))

@@ -28,12 +28,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-from ...core.fp8 import FP8_E5M2, quantize_fp8
+from ...core.fp8 import FP8_E5M2, quantize_fp8, round_to
 from ...core.qsigmoid import qsigmoid_raw
-from .kernel import _SIG_GRID, _SIG_MID, _q_sigmoid, _regroup_gates
+from .. import mosaic
+from .kernel import _q_sigmoid, _regroup_gates
 
 __all__ = ["lstm_cell_bwd_ref", "lstm_cell_bwd_pallas"]
 
@@ -55,7 +55,7 @@ def lstm_cell_bwd_ref(z, c_prev, dh, dc, quantized: bool = True,
         i_t, f_t, o_t, g_t = si, sf, so, tg
     c_prev32 = c_prev.astype(jnp.float32)
     # recompute the EXACT forward cell state, including the storage rounding
-    c32 = (f_t * c_prev32 + i_t * g_t).astype(c_dtype).astype(jnp.float32)
+    c32 = round_to(f_t * c_prev32 + i_t * g_t, c_dtype)
     tanh_c = jnp.tanh(c32)
     tc = quantize_fp8(tanh_c, FP8_E5M2) if quantized else tanh_c
 
@@ -72,24 +72,20 @@ def lstm_cell_bwd_ref(z, c_prev, dh, dc, quantized: bool = True,
     return dz, dc_prev
 
 
-def lstm_cell_bwd_kernel(z_ref, c_ref, dh_ref, dc_ref, mid_ref, grid_ref,
-                         dz_ref, dcp_ref, *, quantized: bool, c_dtype):
+def lstm_cell_bwd_kernel(z_ref, c_ref, dh_ref, dc_ref, dz_ref, dcp_ref, *,
+                         quantized: bool, c_dtype):
     h = c_ref.shape[-1]
     z = z_ref[...].astype(jnp.float32)
     zi, zf, zg, zo = (z[:, i * h : (i + 1) * h] for i in range(4))
     si, sf, so = jax.nn.sigmoid(zi), jax.nn.sigmoid(zf), jax.nn.sigmoid(zo)
     tg = jnp.tanh(zg)
     if quantized:
-        mid = mid_ref[0, :]
-        grid = grid_ref[0, :]
-        i_t = _q_sigmoid(zi, mid, grid)
-        f_t = _q_sigmoid(zf, mid, grid)
-        o_t = _q_sigmoid(zo, mid, grid)
+        i_t, f_t, o_t = _q_sigmoid(zi), _q_sigmoid(zf), _q_sigmoid(zo)
         g_t = tg.astype(jnp.float8_e5m2).astype(jnp.float32)
     else:
         i_t, f_t, o_t, g_t = si, sf, so, tg
     c_prev = c_ref[...].astype(jnp.float32)
-    c32 = (f_t * c_prev + i_t * g_t).astype(c_dtype).astype(jnp.float32)
+    c32 = round_to(f_t * c_prev + i_t * g_t, c_dtype)
     tanh_c = jnp.tanh(c32)
     tc = tanh_c.astype(jnp.float8_e5m2).astype(jnp.float32) if quantized else tanh_c
 
@@ -118,7 +114,6 @@ def lstm_cell_bwd_pallas(
     bb, bh = min(bb, b), min(bh, h)
     assert b % bb == 0 and h % bh == 0, (b, h, bb, bh)
     grid = (b // bb, h // bh)
-    nm = _SIG_MID.size
 
     dz_g, dcp = pl.pallas_call(
         functools.partial(lstm_cell_bwd_kernel, quantized=quantized,
@@ -129,8 +124,6 @@ def lstm_cell_bwd_pallas(
             pl.BlockSpec((bb, bh), lambda i, j: (i, j)),
             pl.BlockSpec((bb, bh), lambda i, j: (i, j)),
             pl.BlockSpec((bb, bh), lambda i, j: (i, j)),
-            pl.BlockSpec((1, nm), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, nm + 1), lambda i, j: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bb, 4 * bh), lambda i, j: (i, j)),
@@ -138,18 +131,16 @@ def lstm_cell_bwd_pallas(
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, 4 * h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), c_prev.dtype),
+            jax.ShapeDtypeStruct((b, h), mosaic.io_dtype(c_prev.dtype)),
         ],
         interpret=interpret,
     )(
-        _regroup_gates(z, h, bh),
-        c_prev,
-        dh,
-        dc,
-        jnp.asarray(_SIG_MID).reshape(1, -1),
-        jnp.asarray(_SIG_GRID).reshape(1, -1),
+        _regroup_gates(mosaic.widen(z), h, bh),
+        mosaic.widen(c_prev),
+        mosaic.widen(dh),
+        mosaic.widen(dc),
     )
-    return _ungroup_gates(dz_g, h, bh), dcp
+    return _ungroup_gates(dz_g, h, bh), dcp.astype(c_prev.dtype)
 
 
 def _ungroup_gates(zg, h, bh):

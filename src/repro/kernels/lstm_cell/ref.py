@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ...core.fp8 import quantize_fp8
+from ...core.fp8 import quantize_fp8, round_to
 from ...core.qsigmoid import qsigmoid_raw
 
 __all__ = ["lstm_cell_ref"]
@@ -27,7 +27,8 @@ def lstm_cell_ref(z, c_prev, quantized: bool = True, c_dtype=jnp.float16):
     else:
         i_t, f_t, o_t = jax.nn.sigmoid(zi), jax.nn.sigmoid(zf), jax.nn.sigmoid(zo)
         g_t = jnp.tanh(zg)
-    c_t = (f_t * c_prev.astype(f_t.dtype) + i_t * g_t).astype(c_dtype)
+    c_t = round_to(f_t * c_prev.astype(f_t.dtype) + i_t * g_t,
+                   c_dtype).astype(c_dtype)
     tc = quantize_fp8(jnp.tanh(c_t.astype(z.dtype))) if quantized else jnp.tanh(c_t.astype(z.dtype))
     h_t = o_t * tc
     return h_t.astype(z.dtype), c_t

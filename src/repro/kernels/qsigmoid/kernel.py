@@ -4,7 +4,8 @@ The standalone version of the sigmoid stage inside the fused LSTM-cell
 kernel: sigma(-|x|) lands in (0, 0.5], is rounded to the nearest entry of
 the 42-value non-positive-branch LUT by a broadcast compare-count against
 the 42 midpoints (the VPU analogue of the paper's reduced-depth LUT), and
-the positive region is mirrored as 1 - Q(sigma(-x)). Registered in
+the positive region is mirrored as 1 - Q(sigma(-x)). The compare-count runs
+as a select chain over compile-time constants (``mosaic.select_lut``). Registered in
 ``kernels.dispatch`` so gate activations outside the fused cell (e.g. the
 RWKV receptance gate) can run the same datapath.
 """
@@ -18,6 +19,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ...core import qsigmoid as _qs
+from .. import mosaic
 
 __all__ = ["qsigmoid_kernel", "qsigmoid_pallas"]
 
@@ -25,13 +27,10 @@ _SIG_GRID = _qs.sigmoid_lut_values().astype(np.float32)  # 43 incl. 0
 _SIG_MID = ((_SIG_GRID[1:] + _SIG_GRID[:-1]) / 2).astype(np.float32)
 
 
-def qsigmoid_kernel(x_ref, mid_ref, grid_ref, out_ref):
+def qsigmoid_kernel(x_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)
     s_neg = jax.nn.sigmoid(-jnp.abs(x))  # in (0, 0.5]
-    gidx = jnp.sum(
-        (s_neg[..., None] > mid_ref[0, :][None, None, :]).astype(jnp.int32), -1
-    )
-    q = jnp.take(grid_ref[0, :], gidx)
+    q = mosaic.select_lut(s_neg, _SIG_MID, _SIG_GRID)
     out_ref[...] = jnp.where(x > 0, 1.0 - q, q).astype(out_ref.dtype)
 
 
@@ -42,20 +41,11 @@ def qsigmoid_pallas(x, *, bm: int = 256, bn: int = 256, interpret: bool = False)
     bm, bn = min(bm, m), min(bn, n)
     assert m % bm == 0 and n % bn == 0, (m, n, bm, bn)
     grid = (m // bm, n // bn)
-    nm = _SIG_MID.size
     return pl.pallas_call(
         qsigmoid_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, nm), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, nm + 1), lambda i, j: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, n), mosaic.io_dtype(x.dtype)),
         interpret=interpret,
-    )(
-        x,
-        jnp.asarray(_SIG_MID).reshape(1, -1),
-        jnp.asarray(_SIG_GRID).reshape(1, -1),
-    )
+    )(mosaic.widen(x)).astype(x.dtype)

@@ -143,14 +143,14 @@ def run_cell(
         mult = {"train": 6.0, "prefill": 2.0, "decode": 2.0}[kind]
         model_flops = mult * active_p * tokens / n_dev  # per device
 
-        compute_s = hlo.flops / HW.PEAK_FLOPS_BF16
-        memory_s = hlo.bytes_accessed / HW.HBM_BW
-        coll_s = hlo.collective_bytes / HW.ICI_BW_PER_LINK
+        compute_s = hlo.flops / HW.peak_flops_bf16
+        memory_s = hlo.bytes_accessed / HW.hbm_bw
+        coll_s = hlo.collective_bytes / HW.ici_bw_per_link
         dom = max(
             ("compute", compute_s), ("memory", memory_s), ("collective", coll_s),
             key=lambda kv: kv[1],
         )[0]
-        memory_s_fl = hlo_fl.bytes_accessed / HW.HBM_BW
+        memory_s_fl = hlo_fl.bytes_accessed / HW.hbm_bw
         top_bytes = sorted(hlo.detail.items(), key=lambda kv: -kv[1])[:20]
         rec.update(
             status="ok",

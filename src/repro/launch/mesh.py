@@ -9,11 +9,14 @@ device state.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import numpy as np
 from jax.sharding import AxisType, Mesh
 
-__all__ = ["make_production_mesh", "make_host_mesh", "HW"]
+__all__ = ["make_production_mesh", "make_host_mesh", "ChipPeaks", "PEAKS",
+           "peaks", "HW"]
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -29,10 +32,42 @@ def make_host_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     return Mesh(devs, axes)
 
 
-class HW:
-    """TPU v5e-class hardware constants for the roofline (per chip)."""
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks for the roofline."""
 
-    PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-    HBM_BW = 819e9  # B/s
-    ICI_BW_PER_LINK = 50e9  # B/s (per link; wire bytes already per-device)
-    HBM_BYTES = 16 * 1024**3
+    peak_flops_bf16: float  # FLOP/s
+    peak_ops_int8: float  # OP/s
+    hbm_bw: float  # B/s
+    hbm_bytes: int
+    ici_bw_per_link: float  # B/s per link (wire bytes already per device)
+    source: str
+
+
+#: Keyed by ``jax.Device.device_kind``. A kind missing here is an error,
+#: never a default: a roofline against another chip's peaks is wrong.
+PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        peak_flops_bf16=197e12,
+        peak_ops_int8=393e12,
+        hbm_bw=819e9,
+        hbm_bytes=16 * 1024**3,
+        ici_bw_per_link=50e9,  # 1,600 Gbit/s per chip over 4 links
+        source='Google Cloud documentation, "TPU v5e" (system architecture)',
+    ),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of one chip kind, e.g. ``jax.devices()[0].device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to launch.mesh.PEAKS with their source"
+        ) from None
+
+
+#: The chip the production meshes above describe (a v5e pod).
+HW = peaks("TPU v5 lite")

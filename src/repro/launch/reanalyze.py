@@ -24,9 +24,9 @@ def reanalyze_record(rec: dict, hlo_dir: str) -> bool:
     n = rec["n_devices"]
     h = analyze_hlo(text, n_partitions=n)
     hf = analyze_hlo(text, n_partitions=n, vmem_scopes=("flashable",))
-    compute_s = h.flops / HW.PEAK_FLOPS_BF16
-    memory_s = h.bytes_accessed / HW.HBM_BW
-    coll_s = h.collective_bytes / HW.ICI_BW_PER_LINK
+    compute_s = h.flops / HW.peak_flops_bf16
+    memory_s = h.bytes_accessed / HW.hbm_bw
+    coll_s = h.collective_bytes / HW.ici_bw_per_link
     dom = max(
         ("compute", compute_s), ("memory", memory_s), ("collective", coll_s),
         key=lambda kv: kv[1],
@@ -50,7 +50,7 @@ def reanalyze_record(rec: dict, hlo_dir: str) -> bool:
             "dominant": dom,
         },
         roofline_flash={
-            "memory_s": hf.bytes_accessed / HW.HBM_BW,
+            "memory_s": hf.bytes_accessed / HW.hbm_bw,
             "bytes": hf.bytes_accessed,
             "discounted_bytes": hf.bytes_by_op.get("vmem-resident(discounted)", 0.0),
         },

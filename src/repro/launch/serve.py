@@ -17,7 +17,7 @@ for the endpoint reference and runbook.
 
   PYTHONPATH=src python -m repro.launch.serve --requests 32 --batch 8 \
       --max-new 32 --policy floatsd8_table6            # reduced config
-  ... --full                                            # paper-scale 85M LM
+  ... --full                                            # paper-scale LM (50.9M params, tied)
   ... --chunk 1 --dense                                 # seed-equivalent loop
   ... --frontend --replicas 2 --workload zipf-prefix    # router + cache
   ... --http --port 8000 --replicas 2                   # network service
@@ -34,6 +34,7 @@ import numpy as np
 from ..configs.base import get_config
 from ..core.policy import get_policy
 from ..models import build
+from . import compile_cache
 from ..serving import (
     ADMISSION_POLICIES,
     HttpServer,
@@ -143,6 +144,7 @@ def main():
                     help="http: disable the request-lifecycle tracer "
                          "(GET /admin/trace then exports an empty trace)")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.http:
         args.frontend = True  # the HTTP layer sits on the router
 

@@ -22,6 +22,7 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from ..configs.base import get_config
 from ..core.policy import get_policy
@@ -35,6 +36,7 @@ from ..distributed.fault_tolerance import (
     StragglerMonitor,
 )
 from ..models import build
+from . import compile_cache
 from ..obs.telemetry import TelemetryLogger
 from ..optim import adam, sgd
 from ..optim.train_state import init_state, make_train_step
@@ -44,9 +46,13 @@ TASK_OPT = {"udpos": ("adam", 1e-3), "snli": ("adam", 1e-3),
 
 
 def make_mesh_for_host():
-    """All addressable devices as a ("data","model") mesh (model=1 on CPU)."""
+    """All addressable devices as a ("data","model") mesh with model=1: the
+    train step runs data parallel over every device of the host."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    # Auto axes: jax.make_mesh defaults to Explicit sharding-in-types, which
+    # the model's gathers do not annotate
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def build_task(args):
@@ -97,6 +103,7 @@ def main():
                     help="JSONL file for TrainTelemetry records "
                     "(default: <ckpt-dir>/telemetry.jsonl)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     policy = get_policy(args.policy)
     mesh = make_mesh_for_host()
@@ -108,20 +115,26 @@ def main():
         step_fn = make_train_step(
             model.loss, opt, policy, lr=lr,
             fused=False if args.no_fused else None, donate=True,
-            telemetry=not args.no_telemetry,
+            telemetry=not args.no_telemetry, mesh=mesh,
         )
+
+        # the state is replicated on every device of the mesh (data
+        # parallel), placed so the first step sees the layout every later
+        # step does and compiles once
+        replicated = NamedSharding(mesh, P())
 
         def init_fn():
             params = model.init(jax.random.PRNGKey(args.seed))
-            return init_state(
+            return jax.device_put(init_state(
                 params, opt, policy, dynamic_scale=args.dynamic_scale
-            )
+            ), replicated)
 
         ckpt = CheckpointManager(args.ckpt_dir, keep=3)
         loop = RestartableLoop(
             ckpt, init_fn, save_every=args.save_every,
             preemption=PreemptionSignal(install_sigterm=True),
             straggler=StragglerMonitor(),
+            shardings=replicated,
             resume=args.resume,
         )
         if loop.resumed:

@@ -33,6 +33,16 @@ __all__ = ["QuantDense", "QuantEmbedding", "quant_weight", "quant_einsum"]
 GRAD_REDUCE_BF16 = os.environ.get("REPRO_GRAD_REDUCE_BF16", "1") != "0"
 
 
+def _precision(*ops):
+    """f32 operands contract at f32 precision, as the kernels' f32 MXU dots
+    do: a TPU's default for an f32 dot is one bf16 pass, which would round
+    the FP16 last-layer activations and the f32 logit gradients to bf16.
+    Narrower operands keep the default (and any enclosing context)."""
+    if all(o.dtype == jnp.float32 for o in ops):
+        return jax.lax.Precision.HIGHEST
+    return None
+
+
 @functools.lru_cache(maxsize=None)
 def _make_einsum_gc(eq: str):
     """einsum with explicit-transpose VJP: dx keeps f32 accumulation; dw is
@@ -44,7 +54,8 @@ def _make_einsum_gc(eq: str):
 
     @jax.custom_vjp
     def f(x, w):
-        return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32)
+        return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32,
+                          precision=_precision(x, w))
 
     def fwd(x, w):
         return f(x, w), (x, w)
@@ -52,10 +63,12 @@ def _make_einsum_gc(eq: str):
     def bwd(res, g):
         x, w = res
         dx = jnp.einsum(
-            f"{in2},{out}->{in1}", w, g, preferred_element_type=jnp.float32
+            f"{in2},{out}->{in1}", w, g, preferred_element_type=jnp.float32,
+            precision=_precision(w, g),
         ).astype(x.dtype)
         dw = jnp.einsum(
-            f"{in1},{out}->{in2}", x, g, preferred_element_type=jnp.bfloat16
+            f"{in1},{out}->{in2}", x, g, preferred_element_type=jnp.bfloat16,
+            precision=_precision(x, g),
         ).astype(w.dtype)
         return dx, dw
 
@@ -87,15 +100,18 @@ def quant_act(x: jax.Array, policy: Policy, site: str = "hidden") -> jax.Array:
 
 def policy_einsum(eq: str, x: jax.Array, w: jax.Array, policy: Policy):
     """The bare matmul primitive all weight sites share: f32 accumulation,
-    bf16 dW emission when the policy quantizes gradients (GRAD_REDUCE_BF16).
+    bf16 dW emission when the policy quantizes gradients (GRAD_REDUCE_BF16)
+    and leaves the gradient sum to the partitioner.
     Operands must already be quantized/cast. Packed weights (either
     format) route to the kernel dispatch layer (inference-only: no VJP
     through codes)."""
     if kd.is_packed(w) or kd.is_packed4(w):
         return kd.packed_einsum(eq, x, w, cast_dtype=policy.cdt())
-    if GRAD_REDUCE_BF16 and policy.grad_quant != "none":
+    if (GRAD_REDUCE_BF16 and policy.grad_quant != "none"
+            and not policy.explicit_grad_sum):
         return _make_einsum_gc(eq)(x, w)
-    return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32)
+    return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32,
+                      precision=_precision(x, w))
 
 
 def quant_einsum(eq: str, x: jax.Array, w: jax.Array, policy: Policy, site: str = "hidden"):

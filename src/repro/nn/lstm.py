@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.fp8 import quantize_fp8
+from ..core.fp8 import quantize_fp8, round_to
 from ..core.policy import Policy
 from ..core.qsigmoid import qsigmoid, qtanh_fp8
 from ..kernels import dispatch as kd
@@ -124,7 +124,8 @@ class LSTMCell:
             i_t, f_t, o_t = jax.nn.sigmoid(zi), jax.nn.sigmoid(zf), jax.nn.sigmoid(zo)
             g_t = jnp.tanh(zg)
         # Eq. (5): FloatSD8 (f,i) x FP products, FP16 cell state
-        c_t = (f_t * state.c.astype(f_t.dtype) + i_t * g_t).astype(c_dt)
+        c_t = round_to(f_t * state.c.astype(f_t.dtype) + i_t * g_t,
+                       c_dt).astype(c_dt)
         # Eq. (6)
         tc = qtanh_fp8(c_t.astype(cdt)) if policy.sigmoid_quant else jnp.tanh(c_t.astype(cdt))
         h_t = (o_t * tc).astype(cdt)
@@ -159,13 +160,15 @@ class LSTMCell:
 
 @functools.lru_cache(maxsize=None)
 def _make_lstm_bptt(pol, packed, masked, reverse, quantized, c_dtype,
-                    afwd, abwd, remat, w_dtype):
+                    afwd, abwd, remat, w_dtype, snap_dw):
     """Build the custom-VJP scan engine for one static configuration.
 
     pol: resolved dispatch backend ("ref"/"pallas"/"auto"); packed: weights
     hoisted as PackedTensor (pallas) vs dense STE (ref); afwd/abwd: the
     activation fake-quant dtypes of the policy's hidden site (None = off);
-    w_dtype: the dense masters' dtype (their cotangent dtype).
+    w_dtype: the dense masters' dtype (their cotangent dtype); snap_dw:
+    the dW kernels snap to FP8 at their flush (off: raw f32 dW, for a
+    data-parallel step to sum before it snaps).
     """
     f32 = jnp.float32
 
@@ -293,8 +296,10 @@ def _make_lstm_bptt(pol, packed, masked, reverse, quantized, c_dtype,
         # weight grads: ONE kernel call each over the whole sequence, FP8
         # emission at the accumulator flush; dXs batched the same way
         dzs_f = dzs.reshape(s * bsz, 4 * h)
-        dwx = kd.matmul_dw(xs.reshape(s * bsz, d), dzs_f, backend=pol)
-        dwh = kd.matmul_dw(hqs.reshape(s * bsz, h), dzs_f, backend=pol)
+        dwx = kd.matmul_dw(xs.reshape(s * bsz, d), dzs_f, quant=snap_dw,
+                           backend=pol)
+        dwh = kd.matmul_dw(hqs.reshape(s * bsz, h), dzs_f, quant=snap_dw,
+                           backend=pol)
         if packed:
             dxs = kd.matmul_dx(dzs_f, wqx.codes, wqx.bias, backend=pol)
         else:
@@ -303,8 +308,9 @@ def _make_lstm_bptt(pol, packed, masked, reverse, quantized, c_dtype,
         db = jnp.sum(dzs_f, axis=0).astype(b.dtype)
         wdt = jnp.dtype(w_dtype)
         if packed:
-            # FP8 dW straight-through to the dense masters (fp8 values are
-            # exactly representable at any master dtype >= fp16)
+            # dW straight-through to the dense masters (FP8 values are exact
+            # at any master dtype >= fp16; raw dW, under explicit_grad_sum,
+            # meets the f32 copies make_train_step differentiates)
             g_masters = (dwx.astype(wdt), dwh.astype(wdt))
             g_wq = (
                 kd.PackedTensor(_f0(wqx.codes), _f0(wqx.bias)),
@@ -401,6 +407,7 @@ class LSTMLayer:
                 kd.backend_policy(None), kd.is_packed(wqx),
                 lengths is not None, self.reverse, policy.sigmoid_quant,
                 c_dt, afwd, abwd, BPTT_REMAT, jnp.dtype(p["wx"].dtype).name,
+                not policy.explicit_grad_sum,
             )
             lens_arr = (
                 jnp.asarray(lengths, jnp.int32)

@@ -19,6 +19,18 @@ Two gradient paths, selected by ``make_train_step``:
     pre-fusion behaviour — plain autodiff BPTT, with the same tree pass
     doing ALL the gradient quantization.
 
+Given a mesh with a "data" axis (of any size, one device included), the
+step runs data parallel under ``shard_map``: each device gets its slice of
+the batch and a replica of the state (Pallas kernels are not partitioned
+automatically) and differentiates its share of the global mean loss,
+taken against f32 copies of the masters, so no partial gradient is
+rounded (``Policy.explicit_grad_sum``: no weight site rounds its dW
+either). The f32 gradients are summed over the axis, the sweep snaps the
+sum to FP8, and only then is it cast to the master dtype (exact: FP8
+values are representable at fp16). One, two or four devices thus compute
+the same update up to f32 summation order. Without a mesh the gradients
+take the masters' dtype as autodiff gives it, before the sweep.
+
 Skip-on-nonfinite keeps dynamic loss scaling sound; with static scaling
 (paper) a nonfinite step is skipped the same way (equivalent to PyTorch's
 GradScaler semantics the baselines use). The finite check, skip select, and
@@ -33,6 +45,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core import loss_scaling as ls
 from ..core.fp8 import grad_quant
@@ -61,7 +74,8 @@ def init_state(params, opt: Optimizer, policy: Policy, dynamic_scale=False) -> T
 
 def make_train_step(loss_fn, opt: Optimizer, policy: Policy, lr: float = 1e-3,
                     grad_clip: float | None = 1.0, fused: bool | None = None,
-                    donate: bool = False, telemetry: bool = False):
+                    donate: bool = False, telemetry: bool = False,
+                    mesh=None):
     """loss_fn(params, batch, policy) -> scalar. Returns a step fn.
 
     ``fused=None`` resolves to ``policy.grad_quant == 'fp8'`` unless
@@ -77,6 +91,9 @@ def make_train_step(loss_fn, opt: Optimizer, policy: Policy, lr: float = 1e-3,
     carry/clamp fractions of the master-weight update. All computed
     inside the jitted step; feed the per-step dicts to a
     ``TelemetryLogger`` for aggregation + JSONL output.
+
+    ``mesh`` with a "data" axis makes the step data parallel over it
+    (module docstring); the batch must divide over the axis.
     """
     if fused is None:
         fused = (
@@ -89,12 +106,29 @@ def make_train_step(loss_fn, opt: Optimizer, policy: Policy, lr: float = 1e-3,
         else policy
     )
 
+    dp = mesh is not None and "data" in mesh.shape
+    n_dp = mesh.shape["data"] if dp else 1
+    if dp:
+        run_policy = run_policy.replace(explicit_grad_sum=True)
+
     def step(state: TrainState, batch):
         def scaled_loss(p):
             l = loss_fn(p, batch, run_policy)
-            return ls.scale_loss(l.astype(jnp.float32), state.scale), l
+            # data parallel: each device differentiates its share of the
+            # global mean (local mean / n_dp), so every per-row gradient
+            # has the one-chip scale, which the FP8 activation-gradient
+            # quantizer (and its underflow) depends on; the shares sum
+            g = l.astype(jnp.float32) / n_dp
+            return ls.scale_loss(g, state.scale), l
 
-        grads, raw_loss = jax.grad(scaled_loss, has_aux=True)(state.params)
+        params = state.params
+        if dp:
+            params = jax.tree_util.tree_map(
+                lambda p: p.astype(jnp.float32), params)
+        grads, raw_loss = jax.grad(scaled_loss, has_aux=True)(params)
+        if dp:
+            grads = jax.lax.psum(grads, "data")
+            raw_loss = jax.lax.pmean(raw_loss, "data")
         # sweep-point telemetry: the loss-scaled values the FP8 quantizer
         # is about to see (saturation/underflow are scale-relative)
         tel = obs_telemetry.fp8_grad_stats(grads) if telemetry else None
@@ -103,6 +137,8 @@ def make_train_step(loss_fn, opt: Optimizer, policy: Policy, lr: float = 1e-3,
             # the leaves the fused backward kernels already emitted on the
             # fp8 grid; quantizes + saturates everything else.
             grads = grad_quant(grads)
+        grads = jax.tree_util.tree_map(
+            lambda g, p: g.astype(p.dtype), grads, state.params)
         grads, finite = ls.unscale_and_check(grads, state.scale)
         if telemetry:
             tel["grad_norm"] = obs_telemetry.layer_grad_norms(grads)
@@ -146,6 +182,11 @@ def make_train_step(loss_fn, opt: Optimizer, policy: Policy, lr: float = 1e-3,
             metrics["tel"] = tel
         return TrainState(state.step + 1, new_params, new_opt, new_scale), metrics
 
+    if dp:
+        step = jax.shard_map(
+            step, mesh=mesh, in_specs=(P(), P("data")), out_specs=P(),
+            check_vma=False,
+        )
     if donate:
         return jax.jit(step, donate_argnums=(0,))
     return step

@@ -319,15 +319,41 @@ def test_backend_precedence_argument_over_context_over_env(monkeypatch):
 
 
 def test_auto_padding_profitability_on_tpu(monkeypatch):
-    """With compiled pallas available (simulated), auto pads only while the
-    padded work stays under PAD_WASTE_MAX; beyond it the oracle wins."""
+    """With compiled pallas available (simulated), auto takes the kernel at
+    any padding waste: the oracle never stands in for it (a decode step
+    of one row pads M 1 -> 8, 8x the work, and still runs the kernel)."""
     monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")  # pretend compiled
     d = kd._choose("x", native=True, waste=1.0, backend="auto")
     assert d.backend == "pallas" and not d.padded
-    d = kd._choose("x", native=False, waste=kd.PAD_WASTE_MAX - 0.1, backend="auto")
-    assert d.backend == "pallas" and d.padded
-    d = kd._choose("x", native=False, waste=kd.PAD_WASTE_MAX + 0.1, backend="auto")
-    assert d.backend == "ref" and "waste" in d.reason
+    for waste in (1.9, 8.0, 64.0):
+        d = kd._choose("x", native=False, waste=waste, backend="auto")
+        assert d.backend == "pallas" and d.padded, d
+        assert f"{waste:.2f}x" in d.reason
+
+
+def test_interpret_refused_on_tpu(monkeypatch):
+    monkeypatch.setattr(kd, "_on_tpu", lambda: True)
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
+    with pytest.raises(kd.OracleRefused, match="REPRO_KERNEL_INTERPRET"):
+        kd.interpret_mode()
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
+    assert kd.interpret_mode() is False
+    monkeypatch.delenv("REPRO_KERNEL_INTERPRET")
+    assert kd.interpret_mode() is False
+
+
+@pytest.mark.parametrize("backend", ["auto", "pallas"])
+def test_no_padded_path_fallback_refused_on_tpu(monkeypatch, backend):
+    """Off a TPU an indivisible shape falls back to the recorded oracle; on
+    one it raises unless the caller asked for ref."""
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
+    d = kd._decide_fallback("rwkv_wkv", False, "S=5 % chunk=16", backend)
+    assert d.backend == "ref" and "no padded path" in d.reason
+    monkeypatch.setattr(kd, "_on_tpu", lambda: True)
+    with pytest.raises(kd.OracleRefused, match="rwkv_wkv: S=5"):
+        kd._decide_fallback("rwkv_wkv", False, "S=5 % chunk=16", backend)
+    assert kd._decide_fallback("rwkv_wkv", False, "", "ref").backend == "ref"
+    assert kd._decide_fallback("rwkv_wkv", True, "", backend).backend == "pallas"
 
 
 def test_stats_counters_accumulate():
@@ -486,14 +512,34 @@ def test_packed4_einsum_matches_dense(eq, xshape, wshape, backend):
 
 
 def test_packed4_einsum_transpose_fallback_is_recorded():
-    """The tied-head layout cannot stream nibbles transposed; the
-    decode+einsum fallback must be a recorded Decision, never silent."""
+    """The tied-head layout (packed along the free axis) no longer falls
+    back to decode+einsum: the kernel takes it as stored, padded, and the
+    Decision is recorded like any other."""
     x = jnp.asarray(_w((2, 80), 0.5))
     p4 = kd.pack4(jnp.asarray(_w((96, 80), 0.05)))
     with kd.use_backend("pallas"):
-        kd.packed_einsum("...d,vd->...v", x, p4)
+        got = kd.packed_einsum("...d,vd->...v", x, p4)
         dec = kd.STATS.last["floatsd4_matmul"]
-    assert dec.backend == "ref" and "transpose" in dec.reason, dec
+    assert dec.backend == "pallas" and dec.padded, dec
+    want = kd.matmul4(x, p4, transpose=True, backend="ref")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("m,n,k", [(8, 256, 128), (3, 95, 81), (16, 33, 200)])
+def test_matmul4_transpose_parity(m, n, k):
+    """x @ decode4(w [N, K] packed along N)^T: kernel (interpreted) vs the
+    oracle, native and padded."""
+    x = jnp.asarray(_w((m, k), 0.5))
+    p4 = kd.pack4(jnp.asarray(_w((n, k), 0.05)))
+    got = kd.matmul4(x, p4, transpose=True, backend="pallas")
+    want = kd.matmul4(x, p4, transpose=True, backend="ref")
+    dense = floatsd4.decode_packed(p4.codes, p4.exps, p4.k)
+    assert got.shape == (m, n)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(want), np.asarray(x @ dense.T), rtol=1e-5, atol=1e-6)
 
 
 def test_hoist_packed4_decodes_for_ref_keeps_codes_for_pallas():

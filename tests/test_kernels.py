@@ -275,3 +275,84 @@ def test_quantize_then_matmul_equals_fakequant_dense():
     np.testing.assert_allclose(
         np.asarray(y_kernel), np.asarray(y_fake), rtol=2e-2, atol=2e-2
     )
+
+
+# ---------------------------------------------------------------------------
+# the Mosaic-lowerable building blocks are exact against what they replace
+# (table gathers and f16 casts, which the TPU kernel compiler refuses; cast
+# pairs, which XLA may drop on a TPU)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "dtype", [jnp.float16, jnp.float8_e5m2, jnp.float8_e4m3fn]
+)
+def test_round_to_matches_cast(dtype):
+    """The integer-op rounding the oracles and the f16 cell kernel use is
+    bit-identical to a cast there and back, on a sweep of f32 patterns."""
+    from repro.core.fp8 import round_to
+
+    bits = np.arange(0, 2**32, 4099, dtype=np.uint64).astype(np.uint32)
+    special = np.array(
+        [0.0, -0.0, np.inf, -np.inf, np.nan, 65504.0, 65519.99, 65520.0,
+         57344.0, 61440.0, 448.0, 463.9, 464.0, 2.0**-14, 2.0**-24,
+         2.0**-25, 2.0**-16, 2.0**-9, 3 * 2.0**-11, -(2.0**-15) * 1.5],
+        np.float32,
+    )
+    x = jnp.asarray(np.concatenate([bits.view(np.float32), special]))
+    got = np.asarray(jax.jit(lambda v: round_to(v, dtype))(x))
+    want = np.asarray(x.astype(dtype).astype(jnp.float32))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
+@pytest.mark.parametrize(
+    "dtype", [jnp.float16, jnp.float8_e5m2, jnp.float8_e4m3fn]
+)
+def test_round_to_gradient_matches_cast(dtype):
+    """Autodiff through ``round_to`` rounds the cotangent as the transpose
+    of the cast pair does (the inline LSTM cell and the FP8 fake-quant are
+    differentiated through it)."""
+    from repro.core.fp8 import round_to
+
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal(4096), jnp.float32)
+    w = jnp.asarray(rng.standard_normal(4096) * 3.0, jnp.float32)
+    got = jax.grad(lambda v: jnp.sum(round_to(v, dtype) * w))(x)
+    want = jax.grad(
+        lambda v: jnp.sum(v.astype(dtype).astype(jnp.float32) * w))(x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("bias", [-126, -9, 0, 120])
+def test_decode_tile_matches_floatsd_decode(bias):
+    from repro.kernels.floatsd_matmul.kernel import decode_tile
+
+    codes = jnp.arange(256, dtype=jnp.uint8).reshape(16, 16)
+    got = np.asarray(decode_tile(codes, jnp.int32(bias)))
+    want = np.asarray(floatsd.decode(codes, bias))
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+def test_mantissa4_matches_lut16():
+    from repro.core import floatsd4
+    from repro.kernels.floatsd4_matmul.kernel import _mantissa4
+
+    got = np.asarray(_mantissa4(jnp.arange(16, dtype=jnp.int32)))
+    np.testing.assert_array_equal(got, floatsd4.LUT16)
+
+
+def test_select_lut_matches_compare_count():
+    from repro.kernels import mosaic
+    from repro.kernels.floatsd_quantize.kernel import _CODES, _MID
+
+    rng = np.random.default_rng(0)
+    x = np.concatenate([
+        rng.uniform(-1.0, float(_MID[-1]) * 1.1, 4096), _MID,
+        np.nextafter(_MID, np.inf), [np.nan],
+    ]).astype(np.float32)
+    got = np.asarray(mosaic.select_lut(jnp.asarray(x), _MID, _CODES))
+    want = _CODES[np.sum(x[:, None] > _MID[None, :], axis=-1)]
+    np.testing.assert_array_equal(got, want)

@@ -259,3 +259,46 @@ def test_fused_loss_trajectory_ref_vs_pallas_interpret():
     pal = np.asarray(_train_losses(50, "pallas"))
     rel = np.max(np.abs(ref - pal) / np.maximum(np.abs(ref), 1e-9))
     assert rel <= 1e-3, rel
+
+
+@pytest.mark.parametrize("policy", [T2, T6], ids=["fp32-master", "fp16-master"])
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_mesh_step_matches_meshless_step(no_bf16_dw, backend, policy):
+    """A step given a "data" mesh (here of one device) differentiates f32
+    copies of the masters, sums raw f32 dW over the axis and snaps the sum
+    in its FP8 sweep; the meshless step snaps the gate dW at the kernel
+    flush. With the oracle's bf16 dW emission off: fp32 masters give
+    bit-identical updates; fp16 masters too for the gate weights, while the
+    meshless step rounds the other gradients to fp16 before their FP8 snap,
+    which moves a few elements by one FP8 step."""
+    from jax.sharding import Mesh
+
+    from repro.data import synthetic
+    from repro.models.lstm_models import WikiText2LM
+    from repro.optim import sgd
+    from repro.optim.train_state import init_state, make_train_step
+
+    model = WikiText2LM(vocab=128, emb=16, hidden=16, n_layers=2)
+    data = synthetic.wikitext2(batch=8, seq=16, vocab=model.vocab, seed=0)
+    batch = {k: jnp.asarray(v) for k, v in next(data.batches).items()}
+    opt = sgd(0.9)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    out = []
+    with kd.use_backend(backend):
+        for m in (None, mesh):
+            state = init_state(model.init(jax.random.PRNGKey(0)), opt, policy)
+            step = jax.jit(make_train_step(model.loss, opt, policy, lr=0.5,
+                                           fused=True, mesh=m))
+            state, metrics = step(state, batch)
+            assert bool(metrics["grads_finite"])
+            out.append(state)
+    trees = [jax.tree_util.tree_leaves_with_path((st.params, st.opt_state))
+             for st in out]
+    for (path, a), (_, b) in zip(*trees):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype
+        gate = jax.tree_util.keystr(path).endswith(("['wx']", "['wh']"))
+        if policy is T2 or gate:
+            np.testing.assert_array_equal(a, b)
+        else:
+            assert np.mean(a != b) <= 0.01, jax.tree_util.keystr(path)
