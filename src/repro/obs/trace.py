@@ -28,6 +28,19 @@ Design points:
     events (whose ``B`` was evicted by the ring) and unterminated ``B``
     events (spans still open at export), so every exported trace has
     matched B/E pairs and loads cleanly in Perfetto / chrome://tracing.
+  * **Profiler bridge**: ``annotate`` is an optional callable returning a
+    context manager (``obs.profiler.install`` sets it to
+    ``jax.profiler.TraceAnnotation``). While the tracer is enabled every
+    span also enters ``annotate(name)`` around its body, so a profiler
+    capture shows the span on the same host timeline as the device's ops;
+    a ``complete()`` event, whose start has passed, is marked there by a
+    zero-length annotation at its end. Disabled, the hook is never called.
+  * **GC spans**: the collector is process-wide, so one tracer records
+    it: with ``gc_spans=True`` (the shared ``TRACER``), ``enable()``
+    registers a ``gc.callbacks`` hook that records every collection as a
+    ``runtime.gc`` span (``generation``; ``collected`` on its end), and
+    ``disable()`` removes it. The lock is re-entrant because a collection
+    can start inside the tracer's own critical section.
 
 A module-level ``TRACER`` is the instance the whole stack shares; the
 ``REPRO_TRACE=1`` environment variable enables it at import time.
@@ -35,9 +48,11 @@ A module-level ``TRACER`` is the instance the whole stack shares; the
 from __future__ import annotations
 
 import collections
+import gc
 import os
 import threading
 import time
+import weakref
 
 __all__ = ["Tracer", "TRACER", "span", "instant", "counter"]
 
@@ -55,6 +70,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -62,15 +80,30 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """Live span: emits a matched B/E pair and feeds the aggregate table."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_end_args", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, args: dict):
         self._tr = tr
         self._name = name
         self._cat = cat
         self._args = args
+        self._end_args = None
+        self._ann = None
+
+    def note(self, **args) -> None:
+        """Args known only inside the body (bytes stored, items handled):
+        they ride on the span's E event, which trace viewers merge with
+        the B event's args."""
+        if self._end_args is None:
+            self._end_args = args
+        else:
+            self._end_args.update(args)
 
     def __enter__(self):
+        annotate = self._tr.annotate
+        if annotate is not None:
+            self._ann = annotate(self._name)
+            self._ann.__enter__()
         self._t0 = time.monotonic_ns() // 1000
         self._tr._push(
             {
@@ -88,33 +121,69 @@ class _Span:
     def __exit__(self, *exc):
         t1 = time.monotonic_ns() // 1000
         tr = self._tr
-        tr._push(
-            {
-                "name": self._name,
-                "cat": self._cat,
-                "ph": "E",
-                "ts": t1,
-                "pid": tr.pid,
-                "tid": threading.get_ident(),
-            }
-        )
+        ev = {
+            "name": self._name,
+            "cat": self._cat,
+            "ph": "E",
+            "ts": t1,
+            "pid": tr.pid,
+            "tid": threading.get_ident(),
+        }
+        if self._end_args is not None:
+            ev["args"] = self._end_args
+        tr._push(ev)
         with tr._lock:
             cnt, tot = tr._agg.get(self._name, (0, 0))
             tr._agg[self._name] = (cnt + 1, tot + (t1 - self._t0))
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
+
+
+def _gc_hook(ref):
+    """A ``gc.callbacks`` entry recording collections on the tracer
+    ``ref`` points to. It holds the tracer weakly, so a tracer dropped
+    while enabled leaves a hook that does nothing."""
+    state = {"span": None}
+
+    def hook(phase, info):
+        tr = ref()
+        if tr is None or not tr._enabled:
+            return
+        if phase == "start":
+            sp = _Span(tr, "runtime.gc", "runtime",
+                       {"generation": info["generation"]})
+            sp.__enter__()
+            state["span"] = sp
+        else:
+            sp, state["span"] = state["span"], None
+            if sp is not None:
+                sp.note(collected=info["collected"])
+                sp.__exit__(None, None, None)
+
+    return hook
 
 
 class Tracer:
     """Thread-safe bounded tracer. See module docstring for semantics."""
 
-    def __init__(self, capacity: int = _DEFAULT_CAPACITY, pid: int = 0):
+    def __init__(
+        self, capacity: int = _DEFAULT_CAPACITY, pid: int = 0,
+        gc_spans: bool = False,
+    ):
         if capacity < 2:
             raise ValueError("capacity must hold at least one B/E pair")
         self.capacity = capacity
         self.pid = pid
         self._events: collections.deque = collections.deque(maxlen=capacity)
-        self._lock = threading.Lock()
+        # re-entrant: the GC hook pushes from inside whatever allocation
+        # triggered the collection, which may be under this lock
+        self._lock = threading.RLock()
         self._enabled = False
+        # profiler bridge: name -> context manager, entered around spans
+        self.annotate = None
+        self.gc_spans = gc_spans
+        self._gc_hook = None
         self.emitted = 0  # total events pushed since last clear()
         self.dropped = 0  # ... of which the ring evicted
         # per-span-name aggregates survive ring eviction: name -> (count,
@@ -128,9 +197,15 @@ class Tracer:
 
     def enable(self) -> None:
         self._enabled = True
+        if self.gc_spans and self._gc_hook is None:
+            self._gc_hook = _gc_hook(weakref.ref(self))
+            gc.callbacks.append(self._gc_hook)
 
     def disable(self) -> None:
         self._enabled = False
+        if self._gc_hook is not None:
+            gc.callbacks.remove(self._gc_hook)
+            self._gc_hook = None
 
     def clear(self) -> None:
         with self._lock:
@@ -161,9 +236,13 @@ class Tracer:
         with other coroutines on the same thread and break nesting, so
         async scopes take a start stamp (``time.monotonic_ns() // 1000``)
         and emit one X event with an explicit duration at completion —
-        X events need no pairing and tolerate same-tid overlap."""
+        X events need no pairing and tolerate same-tid overlap. With a
+        profiler bridge, a zero-length annotation marks its end there."""
         if not self._enabled:
             return
+        if self.annotate is not None:
+            with self.annotate(name):
+                pass
         self._push(
             {
                 "name": name,
@@ -269,7 +348,7 @@ class Tracer:
 
 
 #: Process-wide tracer shared by every layer of the stack.
-TRACER = Tracer()
+TRACER = Tracer(gc_spans=True)
 
 if os.environ.get("REPRO_TRACE", "") not in ("", "0"):
     TRACER.enable()

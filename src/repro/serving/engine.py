@@ -40,6 +40,7 @@ from ..faults import (
     REPLICA_CRASH,
     ReplicaCrash,
 )
+from ..obs import profiler
 from ..obs.trace import TRACER
 from .metrics import ServeMetrics
 from .scheduler import Request, Scheduler
@@ -47,6 +48,9 @@ from .state_pool import StatePool, masked_reset
 from .weight_store import WEIGHT_FORMATS, WeightStore, unpack_tree
 
 __all__ = ["ServeEngine", "Lane"]
+
+# the serving stack's spans also land on the JAX profiler's clock
+profiler.install()
 
 
 class Lane:
@@ -302,7 +306,8 @@ class ServeEngine:
         return (self.active_lanes + len(self.scheduler)) / self.lanes_n
 
     # -- lane lifecycle --------------------------------------------------
-    def _arm_free_lanes(self) -> None:
+    def _arm_free_lanes(self) -> int:
+        """Bind queued requests to free lanes; returns the lanes armed."""
         armed = 0
         for i in range(self.lanes_n):
             # `while`, not `if`: a full prefix-cache hit with max_new == 1
@@ -313,7 +318,7 @@ class ServeEngine:
                     # pacing: spread admissions over steps so a warm burst
                     # (cheap full hits arriving faster than lanes drain)
                     # cannot monopolize every freed lane in one round
-                    return
+                    return armed
                 if self._lane_used[i] and not self._rearmable:
                     raise RuntimeError(
                         "cannot re-arm a used lane: this model's cache has "
@@ -393,6 +398,7 @@ class ServeEngine:
                         self._retire(i)
                         continue
                 break
+        return armed
 
     def _retire(self, i: int, status: str = "done", reason: str | None = None) -> None:
         """Unbind lane ``i`` terminally. ``status="done"`` is the normal
@@ -421,10 +427,7 @@ class ServeEngine:
                 [req.prompt, np.asarray(req.out[:-1], np.int32)]
             )
             if self.prefix_cache.wants(key, len(key)):
-                with TRACER.span("cache.insert", cat="cache", rid=req.rid):
-                    self.prefix_cache.insert(
-                        key, self.pool.extract(i), next_token=req.out[-1]
-                    )
+                self._cache_insert("retire", i, key, next_token=req.out[-1])
         if status == "done":
             self.metrics.on_retire(req, now)
         elif status == "numeric_error":
@@ -454,6 +457,22 @@ class ServeEngine:
                     new_tokens=len(req.out), reason=reason or "cancelled",
                 )
         self._lanes[i] = None
+
+    def _cache_insert(self, kind: str, i: int, key, next_token=None) -> None:
+        """Store lane ``i``'s state under ``key`` in the prefix cache. The
+        ``cache.insert`` span covers the lane's extract (device to host)
+        as well as the cache's own work; ``kind`` names the site:
+        ``block`` (mid-prefill snapshot), ``prompt`` (prompt end) or
+        ``retire``."""
+        cache = self.prefix_cache
+        if not TRACER.enabled:
+            cache.insert(key, self.pool.extract(i), next_token=next_token)
+            return
+        with TRACER.span("cache.insert", cat="cache", kind=kind,
+                         rid=self._lanes[i].req.rid) as sp:
+            sp.note(bytes=cache.insert(
+                key, self.pool.extract(i), next_token=next_token
+            ))
 
     # -- cancellation ----------------------------------------------------
     def cancel(self, rid: int, reason: str = "cancelled") -> bool:
@@ -580,7 +599,11 @@ class ServeEngine:
         if FAULTS.enabled:
             self._check_faults()
         self._maybe_preempt()
-        self._arm_free_lanes()
+        if TRACER.enabled and self.scheduler and None in self._lanes:
+            with TRACER.span("engine.admit", cat="engine") as sp:
+                sp.note(lanes=self._arm_free_lanes())
+        else:
+            self._arm_free_lanes()
         active = [i for i, l in enumerate(self._lanes) if l is not None]
         if not active:
             return False
@@ -639,8 +662,11 @@ class ServeEngine:
                 self.pool.caches,
                 jnp.asarray(reset),
             )
-            nxt = np.asarray(nxt)  # sync point: step outputs materialized
-            ok = np.asarray(ok)
+            # the host waits here for the device (the step was dispatched
+            # asynchronously): the wait is device time, the rest host time
+            with TRACER.span("engine.wait", cat="engine"):
+                nxt = np.asarray(nxt)  # sync point: step outputs materialized
+                ok = np.asarray(ok)
         self.pool.swap(caches)
         if FAULTS.enabled and FAULTS.fire(NONFINITE_LOGITS, key=self.replica):
             # Poison the host copy of one active lane's health flag: the
@@ -657,49 +683,49 @@ class ServeEngine:
             useful=int(ks.sum()),
             any_prefill=any_prefill,
         )
-        now = time.monotonic()
-        cache = self.prefix_cache
-        for i in active:
-            lane = self._lanes[i]
-            if not ok[i]:
-                # Nonfinite logits: never sample from NaN (the argmax
-                # result is garbage), never let the poisoned state reach
-                # the prefix cache or the next step — retire the request
-                # with a distinct status and fold the lane wipe into the
-                # reset mask, exactly like a cancel.
-                self._retire(i, status="numeric_error",
-                             reason="nonfinite_logits")
-                continue
-            if lane.prefilling:
-                lane.pos += int(ks[i])
-                self.metrics.prompt_tokens += int(ks[i])
-                if not lane.prefilling:
-                    # final prompt chunk consumed: this step's last valid
-                    # logit is the first generated token
-                    self._emit(lane, int(nxt[i]), now, first=True)
-                    if cache is not None and cache.wants(
-                        lane.req.prompt, lane.req.prompt_len
+        with TRACER.span("engine.post", cat="engine"):
+            now = time.monotonic()
+            cache = self.prefix_cache
+            for i in active:
+                lane = self._lanes[i]
+                if not ok[i]:
+                    # Nonfinite logits: never sample from NaN (the argmax
+                    # result is garbage), never let the poisoned state reach
+                    # the prefix cache or the next step — retire the request
+                    # with a distinct status and fold the lane wipe into the
+                    # reset mask, exactly like a cancel.
+                    self._retire(i, status="numeric_error",
+                                 reason="nonfinite_logits")
+                    continue
+                if lane.prefilling:
+                    lane.pos += int(ks[i])
+                    self.metrics.prompt_tokens += int(ks[i])
+                    if not lane.prefilling:
+                        # final prompt chunk consumed: this step's last valid
+                        # logit is the first generated token
+                        self._emit(lane, int(nxt[i]), now, first=True)
+                        if cache is not None and cache.wants(
+                            lane.req.prompt, lane.req.prompt_len
+                        ):
+                            # state after the whole prompt + its exact greedy
+                            # continuation -> future identical prompts skip
+                            # prefill entirely
+                            self._cache_insert(
+                                "prompt", i, lane.req.prompt,
+                                next_token=int(nxt[i]),
+                            )
+                    elif cache is not None and cache.wants_snapshot(
+                        lane.req.prompt, lane.pos
                     ):
-                        # state after the whole prompt + its exact greedy
-                        # continuation -> future identical prompts skip
-                        # prefill entirely
-                        cache.insert(
-                            lane.req.prompt,
-                            self.pool.extract(i),
-                            next_token=int(nxt[i]),
+                        # block-boundary snapshot mid-prefill: what makes
+                        # *shared-prefix* (not just identical) prompts hit
+                        self._cache_insert(
+                            "block", i, lane.req.prompt[: lane.pos]
                         )
-                elif cache is not None and cache.wants_snapshot(
-                    lane.req.prompt, lane.pos
-                ):
-                    # block-boundary snapshot mid-prefill: what makes
-                    # *shared-prefix* (not just identical) prompts hit
-                    cache.insert(
-                        lane.req.prompt[: lane.pos], self.pool.extract(i)
-                    )
-            else:
-                self._emit(lane, int(nxt[i]), now)
-            if lane.req.done:
-                self._retire(i)
+                else:
+                    self._emit(lane, int(nxt[i]), now)
+                if lane.req.done:
+                    self._retire(i)
         return True
 
     def _emit(self, lane: Lane, tok: int, now: float, first: bool = False) -> None:

@@ -49,6 +49,7 @@ import numpy as np
 
 from ...core import fp8
 from ...faults import CACHE_CORRUPT, FAULTS
+from ...obs.trace import TRACER
 
 __all__ = ["PrefixCache", "CacheEntry", "CacheHit", "entry_checksum"]
 
@@ -243,8 +244,9 @@ class PrefixCache:
         return node.entry
 
     # -- insert / evict --------------------------------------------------
-    def insert(self, tokens, states, next_token: Optional[int] = None) -> None:
-        """Store the state reached after consuming ``tokens``.
+    def insert(self, tokens, states, next_token: Optional[int] = None) -> int:
+        """Store the state reached after consuming ``tokens``; returns the
+        entry's bytes (0 for an empty key, which stores nothing).
 
         Quantizes to FP8 and copies to host immediately — the source arrays
         may alias the engine's donated state slab, which the next jitted
@@ -253,7 +255,7 @@ class PrefixCache:
         """
         toks = tuple(int(t) for t in np.asarray(tokens).reshape(-1))
         if not toks:
-            return
+            return 0
         states_fp8 = jax.tree_util.tree_map(
             lambda x: np.asarray(fp8.cast_fp8(jnp.asarray(x), self.state_dtype)),
             states,
@@ -294,8 +296,14 @@ class PrefixCache:
         self._lru[toks] = entry
         self.nbytes += nbytes
         self.insertions += 1
-        while self.nbytes > self.budget_bytes and self._lru:
-            self._evict_lru()
+        if self.nbytes > self.budget_bytes:
+            with TRACER.span("cache.evict", cat="cache") as sp:
+                evicted = 0
+                while self.nbytes > self.budget_bytes and self._lru:
+                    self._evict_lru()
+                    evicted += 1
+                sp.note(entries=evicted)
+        return nbytes
 
     def _evict_key(self, key: tuple) -> None:
         """Targeted eviction (corrupt entry): rotate the key to the LRU

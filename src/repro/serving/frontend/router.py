@@ -382,7 +382,10 @@ class Router:
             ):
                 self.cancel(ticket.rid, reason="deadline_expired")
 
-    def _deliver(self) -> None:
+    def _deliver(self) -> int:
+        """Hand new tokens to their tickets' callbacks and settle finished
+        tickets; returns the tokens delivered."""
+        delivered = 0
         for ticket in list(self._inflight.values()):
             req = ticket.req
             if ticket.abandoned:
@@ -393,6 +396,7 @@ class Router:
                 if ticket.on_token is not None:
                     for tok in req.out[ticket.sent :]:
                         ticket.on_token(tok)
+                delivered += len(req.out) - ticket.sent
                 ticket.sent = len(req.out)
             if req.status == "numeric_error":
                 # the engine's nonfinite guard retired it terminally; the
@@ -416,6 +420,7 @@ class Router:
                 # every finished request's tokens (the caller has the
                 # Ticket; aggregates live in self.tenants / engine metrics)
                 self._tickets.pop(ticket.rid, None)
+        return delivered
 
     # -- replica health --------------------------------------------------
     @property
@@ -521,12 +526,17 @@ class Router:
                         # resubmitted work land on the healthy pool.
                         self._on_step_failure(i, exc)
                         progressed = True  # health state advanced
-            self._deliver()
             if TRACER.enabled:
-                # predicted-cost counter tracks (cost.<op>) alongside the
-                # pump spans, so the trace viewer shows analytical
-                # FLOPs/bytes accumulating against wall time
-                kernel_dispatch.LEDGER.emit_counters(TRACER)
+                with TRACER.span("router.deliver", cat="router") as sp:
+                    sp.note(tokens=self._deliver())
+                TRACER.counter(
+                    "serve.queue", cat="router",
+                    queued=len(self._queue),
+                    waiting=sum(len(e.scheduler) for e in self.engines),
+                    lanes_busy=sum(e.active_lanes for e in self.engines),
+                )
+            else:
+                self._deliver()
         return progressed or bool(self._queue) or bool(self._inflight)
 
     def drain(self) -> None:

@@ -563,9 +563,12 @@ class HttpServer:
         )
 
     def _trace(self) -> bytes:
-        """GET /admin/trace: the tracer ring as Chrome trace-event JSON.
+        """GET /admin/trace: the tracer ring as Chrome trace-event JSON,
+        ending with one ``cost.<op>`` counter sample per op (the cost
+        ledger's cumulative predicted FLOPs/bytes at export time).
         The tracer snapshots under its own lock, so this does not need the
         pump lock (and must not hold it: the export can be MBs)."""
+        kernel_dispatch.LEDGER.emit_counters(TRACER)
         return json_response(200, TRACER.chrome_trace())
 
     async def _drain(self) -> bytes:

@@ -469,6 +469,123 @@ def test_engine_rejects_cache_with_non_rearmable_pool():
 
 
 # ---------------------------------------------------------------------------
+# tracing inside a pump
+# ---------------------------------------------------------------------------
+
+
+def _traced_pumps(router):
+    """Pump ``router`` dry under the shared tracer; (events, pumps)."""
+    from repro.obs.trace import TRACER
+
+    TRACER.clear()
+    TRACER.enable()
+    pumps = 0
+    try:
+        while True:
+            pumps += 1
+            if not router.pump():
+                break
+    finally:
+        TRACER.disable()
+    return TRACER.events(), pumps
+
+
+def _spans(events, name):
+    """[(B event, E event)] of the completed spans called ``name``."""
+    open_, out = {}, []
+    for e in events:
+        if e["name"] != name:
+            continue
+        if e["ph"] == "B":
+            open_.setdefault(e["tid"], []).append(e)
+        elif e["ph"] == "E":
+            out.append((open_[e["tid"]].pop(), e))
+    return out
+
+
+def test_traced_pump_spans_host_work_inside_the_pump():
+    """One traced run through the router: every prefix-cache insert site
+    is spanned with its kind and bytes, the device wait nests inside the
+    step, each pump samples the queue once, and no pump emits cost
+    counters (those are written once, at export)."""
+    model = tiny_model()
+    router = Router.build(
+        model, tiny_params(model), POLICY, prefix_cache=PrefixCache(block=4),
+        lanes=2, chunk=4,
+    )
+    rng = np.random.default_rng(5)
+    for _ in range(3):
+        router.submit(rng.integers(0, model.vocab, 10).astype(np.int32),
+                      max_new=3)
+    events, pumps = _traced_pumps(router)
+
+    inserts = _spans(events, "cache.insert")
+    assert {b["args"]["kind"] for b, _ in inserts} == {"block", "prompt", "retire"}
+    assert all(e["args"]["bytes"] > 0 for _, e in inserts)
+
+    steps = _spans(events, "engine.step")
+    waits = _spans(events, "engine.wait")
+    assert len(waits) == len(steps) > 0
+    for (sb, se), (wb, we) in zip(steps, waits):
+        assert sb["tid"] == wb["tid"]
+        assert sb["ts"] <= wb["ts"] <= we["ts"] <= se["ts"]
+    assert len(_spans(events, "engine.post")) == len(steps)
+
+    assert len(_spans(events, "router.pump")) == pumps
+    queue = [e for e in events if e["name"] == "serve.queue"]
+    assert len(queue) == pumps
+    assert set(queue[0]["args"]) == {"queued", "waiting", "lanes_busy"}
+    delivered = sum(e["args"]["tokens"] for _, e in _spans(events, "router.deliver"))
+    assert delivered == 3 * 3
+    admits = _spans(events, "engine.admit")
+    assert sum(e["args"]["lanes"] for _, e in admits) == 3
+    assert not [e for e in events if e["name"].startswith("cost.")]
+
+
+def test_prefix_cache_eviction_is_spanned_with_entries_evicted():
+    from repro.obs.trace import TRACER
+
+    # an entry holds 16 FP8 state bytes + 8 key tokens x 4: two overflow 60
+    cache = PrefixCache(budget_bytes=60, block=4)
+    TRACER.clear()
+    TRACER.enable()
+    try:
+        cache.insert(np.arange(8), fake_states(1))
+        cache.insert(np.arange(8) + 100, fake_states(2))
+    finally:
+        TRACER.disable()
+    evicts = _spans(TRACER.events(), "cache.evict")
+    assert cache.evictions == 1 and len(cache) == 1
+    assert [e["args"]["entries"] for _, e in evicts] == [1]
+
+
+def test_profiler_capture_holds_program_spans(tmp_path):
+    """With the profiler bridge the program's spans are host events of a
+    ``jax.profiler`` capture, on the clock of the device's ops."""
+    from jax.profiler import ProfileData
+
+    model = tiny_model()
+    router = Router.build(model, tiny_params(model), POLICY, lanes=2, chunk=4)
+    router.submit(np.arange(6, dtype=np.int32), max_new=2)
+    router.drain()  # compile outside the capture
+    router.submit(np.arange(6, dtype=np.int32) + 7, max_new=2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _traced_pumps(router)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = list(tmp_path.rglob("*.xplane.pb"))
+    names = {
+        e.name
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+    assert {"router.pump", "engine.step", "engine.wait"} <= names
+
+
+# ---------------------------------------------------------------------------
 # acceptance: zipf-prefix workload, warm vs cold
 # ---------------------------------------------------------------------------
 

@@ -548,6 +548,34 @@ def test_admin_trace_exports_valid_chrome_trace_with_lifecycle_spans():
     assert all("lanes" in a for a in step_args)
 
 
+def test_admin_trace_samples_cost_tracks_once_at_export():
+    """The cost.<op> counter tracks are written when the trace is exported,
+    one sample per op after the last pump, not re-sampled every pump; the
+    export still carries the tracks check_trace.py requires."""
+    prompts = prompts_for(tiny_model(), 2, seed=13)
+
+    async def main():
+        server, task = await start_server(make_router(cache=PrefixCache(block=4)))
+        TRACER.clear()
+        try:
+            async with Client(server.host, server.port) as c:
+                for p in prompts:
+                    await c.generate(p, max_new=3)
+                return await c.trace()
+        finally:
+            await stop_server(server, task)
+
+    trace = asyncio.run(main())
+    assert validate_trace(trace) == []
+    evs = trace["traceEvents"]
+    costs = [e for e in evs if e["name"].startswith("cost.")]
+    names = [e["name"] for e in costs]
+    assert {"cost.floatsd_matmul", "cost.lstm_cell"} <= set(names)
+    assert len(names) == len(set(names))
+    last_pump = max(e["ts"] for e in evs if e["name"] == "router.pump")
+    assert min(e["ts"] for e in costs) >= last_pump
+
+
 def test_debug_flag_returns_phase_breakdown():
     """`"debug": true` adds the queue/prefill/decode decomposition to
     /v1/generate and to the SSE terminal done event; absent by default;

@@ -214,6 +214,107 @@ def test_disabled_tracer_overhead_is_negligible():
 
 
 # ---------------------------------------------------------------------------
+# profiler bridge, GC spans, compile events
+# ---------------------------------------------------------------------------
+
+
+class _RecordingAnnotate:
+    """A fake ``annotate`` hook logging each enter and exit."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Cm:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return _Cm()
+
+
+def test_annotate_hook_wraps_spans_in_order():
+    rec = _RecordingAnnotate()
+    t = Tracer()
+    t.annotate = rec
+    t.enable()
+    with t.span("outer"):
+        with t.span("inner") as sp:
+            sp.note(items=3)
+    t.complete("async", time.monotonic_ns() // 1000, 5)
+    assert rec.log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner"), ("exit", "outer"),
+                       ("enter", "async"), ("exit", "async")]
+    # args noted inside the body ride on the E event
+    inner_e = [e for e in t.events() if e["name"] == "inner" and e["ph"] == "E"]
+    assert inner_e[0]["args"] == {"items": 3}
+    assert validate_trace(t.chrome_trace()) == []
+
+
+def test_disabled_tracer_never_calls_annotate():
+    rec = _RecordingAnnotate()
+    t = Tracer()
+    t.annotate = rec
+    with t.span("a") as sp:
+        sp.note(x=1)
+    t.complete("b", 0, 1)
+    t.instant("c")
+    assert rec.log == [] and t.events() == []
+
+
+def test_gc_spans_recorded_while_enabled_and_hook_removed_on_disable():
+    import gc
+
+    before = list(gc.callbacks)
+    t = Tracer(gc_spans=True)
+    t.enable()
+    assert len(gc.callbacks) == len(before) + 1
+    gc.collect()
+    t.disable()
+    assert gc.callbacks == before
+    gc.collect()  # no longer recorded
+    evs = [e for e in t.events() if e["name"] == "runtime.gc"]
+    assert [e["ph"] for e in evs] == ["B", "E"]
+    assert evs[0]["args"] == {"generation": 2}
+    assert isinstance(evs[1]["args"]["collected"], int)
+    assert validate_trace(t.chrome_trace()) == []
+    # a tracer without gc_spans leaves gc.callbacks alone
+    plain = Tracer()
+    plain.enable()
+    assert gc.callbacks == before
+
+
+def test_compile_listener_emits_runtime_compile_only_while_enabled():
+    from repro.obs import profiler
+
+    t = Tracer()
+    profiler.install(t)
+    profiler.install(t)  # idempotent: one listener
+    assert t.annotate is jax.profiler.TraceAnnotation
+    x = np.ones((3,), np.float32)
+
+    def traced_fresh_fn(v):
+        return v * 3.0 + 1.0
+
+    t.enable()
+    jax.jit(traced_fresh_fn)(x).block_until_ready()
+    t.disable()
+
+    def quiet_fresh_fn(v):
+        return v * 5.0 - 2.0
+
+    jax.jit(quiet_fresh_fn)(x).block_until_ready()
+    evs = [e for e in t.events() if e["name"] == "runtime.compile"]
+    mine = [e for e in evs if "traced_fresh_fn" in e["args"]["fun"]]
+    assert len(mine) == 1 and mine[0]["ph"] == "X" and mine[0]["dur"] >= 0
+    assert not any("quiet_fresh_fn" in e["args"]["fun"] for e in evs)
+
+
+# ---------------------------------------------------------------------------
 # quantization-health telemetry
 # ---------------------------------------------------------------------------
 
