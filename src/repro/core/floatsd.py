@@ -40,6 +40,7 @@ __all__ = [
     "quantize_ste",
     "encode",
     "decode",
+    "select_lut",
     "pack",
     "unpack",
     "partial_product_count",
@@ -73,9 +74,6 @@ def _nzd(m: int, s: int) -> int:
 
 
 MANTISSA_VALUES, MANTISSA_TO_SD = _build_mantissas()
-_MANTISSA_J = jnp.asarray(MANTISSA_VALUES)
-# midpoints for nearest-value rounding over the 31-entry grid
-_MANTISSA_MID = jnp.asarray((MANTISSA_VALUES[1:] + MANTISSA_VALUES[:-1]) / 2.0)
 
 
 def _value_grid_np() -> np.ndarray:
@@ -99,8 +97,8 @@ def floatsd8_value_grid(bias: int = 0) -> np.ndarray:
 # pair used by ``encode``; chooses the smallest exponent (finest grid) that
 # represents the value exactly.
 def _grid_codes() -> tuple[np.ndarray, np.ndarray]:
-    es = np.zeros(_GRID_POS.size, dtype=np.int8)
-    mi = np.zeros(_GRID_POS.size, dtype=np.int8)
+    es = np.zeros(_GRID_POS.size, dtype=np.int32)
+    mi = np.zeros(_GRID_POS.size, dtype=np.int32)
     for i, v in enumerate(_GRID_POS):
         found = False
         for e in range(EXP_LEVELS):
@@ -118,6 +116,29 @@ def _grid_codes() -> tuple[np.ndarray, np.ndarray]:
 
 
 _GRID_E, _GRID_MIDX = _grid_codes()
+_GRID_POS32 = _GRID_POS.astype(np.float32)
+_GRID_MID32 = _GRID_MID.astype(np.float32)
+# per grid slot, the code of +value in the low byte and of -value in the
+# next: code = e << 5 | mantissa index, the sign folded into the index as
+# 30 - index (the sorted mantissa set is symmetric around index 15 == 0.0)
+_GRID_CODES = ((_GRID_E << 5) | _GRID_MIDX) | (((_GRID_E << 5) | (30 - _GRID_MIDX)) << 8)
+
+
+def select_lut(x: jax.Array, mids: np.ndarray, values: np.ndarray) -> jax.Array:
+    """``values[#(mids < x)]`` for ascending ``mids`` (len(values) ==
+    len(mids) + 1): the compare-count LUT lookup as a select chain over
+    compile-time constants. ``x > mids[k]`` holds for a prefix of k, so the
+    last select that fires picks exactly the counted index (NaN -> values[0],
+    as the count gives).
+
+    Elementwise, so XLA fuses the chain into one vectorised loop, and Mosaic
+    lowers it inside a Pallas kernel; a gather from a constant table does
+    neither (XLA on TPU runs it element by element, Mosaic refuses it)."""
+    assert len(values) == len(mids) + 1
+    out = jnp.full(x.shape, values[0], np.asarray(values).dtype)
+    for m, v in zip(np.asarray(mids).tolist(), np.asarray(values)[1:]):
+        out = jnp.where(x > m, v, out)
+    return out
 
 
 class QuantResult(NamedTuple):
@@ -163,24 +184,6 @@ def fit_bias(x: jax.Array) -> jax.Array:
     return _clamp_bias(raw)
 
 
-def _count_idx(mids: jax.Array, n: jax.Array) -> jax.Array:
-    """index = #(mids < n), i.e. searchsorted(mids, n, side='left') — but as
-    one broadcast compare-count instead of jnp.searchsorted. searchsorted
-    lowers to a log2(len)-trip while loop whose body round-trips the full
-    tensor each iteration (~7x the HBM traffic on activation-sized inputs,
-    measured in EXPERIMENTS.md §Perf); the compare-count is a single fusion
-    and is exactly what the Pallas quantize kernel does on TPU."""
-    return jnp.sum(
-        (n[..., None] > mids[(None,) * n.ndim]).astype(jnp.int32), axis=-1
-    )
-
-
-def _round_mantissa(m: jax.Array) -> jax.Array:
-    """Nearest value in the 31-entry mantissa grid (regular rounding)."""
-    idx = _count_idx(_MANTISSA_MID, m)
-    return _MANTISSA_J[idx]
-
-
 def quantize(x: jax.Array, bias: jax.Array | int | None = None) -> QuantResult:
     """Exact nearest-representable-value FloatSD8 quantization (fake-quant).
 
@@ -198,8 +201,7 @@ def quantize(x: jax.Array, bias: jax.Array | int | None = None) -> QuantResult:
     # clamp into representable window, saturating rounding at the top
     top = _GRID_POS[-1]
     n = jnp.clip(n, 0.0, top)
-    idx = _count_idx(jnp.asarray(_GRID_MID, jnp.float32), n)
-    q = jnp.asarray(_GRID_POS, jnp.float32)[idx] * scale
+    q = select_lut(n, _GRID_MID32, _GRID_POS32) * scale
     out = jnp.sign(xf) * q
     return QuantResult(out.astype(x.dtype), bias)
 
@@ -226,21 +228,22 @@ quantize_ste.defvjp(_ste_fwd, _ste_bwd)
 
 
 def encode(x: jax.Array, bias: jax.Array | int | None = None) -> tuple[jax.Array, jax.Array]:
-    """Quantize and pack to int8 codes: sign<<7 | e<<5 ... actually the
-    paper's layout is 3-bit exponent + 5-bit SD-group code. We use:
+    """Quantize and pack to uint8 codes. The paper's layout is a 3-bit
+    exponent field and a 5-bit SD-group code; here
 
         code8 = (e << 5) | m_idx        (m_idx in [0, 30])
 
-    with the sign folded into m_idx (the mantissa set is symmetric). Returns
-    (codes uint8, bias int32).
+    with m_idx the index into the 31 sorted ``MANTISSA_VALUES``, which
+    carries the sign (the set is symmetric; index 15 is 0.0). Each grid
+    value takes its smallest exponent. Returns (codes uint8, bias int32).
 
     Precondition: ``x`` must be finite. uint8 codes carry no NaN/inf
-    representation, and the grid-index search maps NaN to code 0 (every
-    ``>`` comparison is False) — a silent finite encoding. Inf saturates
-    to the top grid point (the documented clip behaviour). Callers that
-    can see corrupt data must check first; ``serving.weight_store
-    .pack_tree`` (the deployment path) raises on nonfinite weights before
-    calling this.
+    representation, and the grid-slot search maps NaN to slot 0, the code
+    of 0.0 (every ``>`` comparison is False) — a silent finite encoding.
+    Inf saturates to the top grid point (the documented clip behaviour).
+    Callers that can see corrupt data must check first; ``serving
+    .weight_store.pack_tree`` (the deployment path) raises on nonfinite
+    weights before calling this.
     """
     if bias is None:
         bias = fit_bias(x)
@@ -248,23 +251,22 @@ def encode(x: jax.Array, bias: jax.Array | int | None = None) -> tuple[jax.Array
     xf = x.astype(jnp.float32)
     scale = exp2i(bias)
     n = jnp.clip(jnp.abs(xf) / scale, 0.0, _GRID_POS[-1])
-    gidx = _count_idx(jnp.asarray(_GRID_MID, jnp.float32), n)
-    e = jnp.asarray(_GRID_E, jnp.int32)[gidx]
-    midx = jnp.asarray(_GRID_MIDX, jnp.int32)[gidx]  # index of |mantissa|
-    # map to signed mantissa index: grid is symmetric, index 15 == 0.0
-    neg = xf < 0
-    midx_signed = jnp.where(neg, 30 - midx, midx)
-    # |mantissa| indices are in [15, 30]; negatives map to [0, 15]
-    code = (e << 5) | midx_signed
+    pair = select_lut(n, _GRID_MID32, _GRID_CODES)
+    code = jnp.where(xf < 0, pair >> 8, pair & 0xFF)
     return code.astype(jnp.uint8), bias
 
 
 def decode(codes: jax.Array, bias: jax.Array | int, dtype=jnp.float32) -> jax.Array:
-    """Decode uint8 FloatSD8 codes back to real values."""
+    """Decode uint8 FloatSD8 codes back to real values, in closed form:
+    the positive half of the sorted mantissas, in quarters, is 1..10 and
+    then 14..18 (the grid's 2.5 -> 3.5 hole), so with ``a = |m_idx - 15|``
+    ``|mantissa| = (a + 3 * (a > 10)) / 4``. Index 31 reads as 30."""
     c = codes.astype(jnp.int32)
     e = c >> 5
-    midx = c & 0x1F
-    m = _MANTISSA_J[jnp.clip(midx, 0, 30)]
+    d = jnp.clip(c & 0x1F, 0, 30) - 15
+    a = jnp.abs(d)
+    q = a + 3 * (a > 10).astype(jnp.int32)
+    m = jnp.where(d < 0, -q, q).astype(jnp.float32) * 0.25
     bias = _clamp_bias(bias)
     return (m * exp2i(e + bias)).astype(dtype)
 
@@ -279,14 +281,11 @@ def partial_product_count(codes: jax.Array) -> jax.Array:
 
     Used by the Table-VII complexity model.
     """
-    midx = (codes.astype(jnp.int32)) & 0x1F
-    m_abs = jnp.abs(_MANTISSA_J[jnp.clip(midx, 0, 30)])
+    midx = jnp.clip(codes.astype(jnp.int32) & 0x1F, 0, 30)
     nz = jnp.asarray(
         [_nzd(*MANTISSA_TO_SD[float(v)]) for v in MANTISSA_VALUES], jnp.int32
     )
-    idx = jnp.searchsorted(jnp.asarray(MANTISSA_VALUES), _MANTISSA_J[midx])
-    del m_abs
-    return nz[idx]
+    return nz[midx]
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .floatsd import _count_idx, exp2i
+from .floatsd import exp2i
 
 __all__ = [
     "MANTISSA_VALUES",
@@ -78,6 +78,20 @@ SPARE_CODE = 15
 LUT16 = np.zeros(16, dtype=np.float32)
 LUT16[:15] = MANTISSA_VALUES
 _LUT16_J = jnp.asarray(LUT16)
+
+
+def _count_idx(mids: jax.Array, n: jax.Array) -> jax.Array:
+    """index = #(mids < n), i.e. searchsorted(mids, n, side='left') — but as
+    one broadcast compare-count instead of jnp.searchsorted. searchsorted
+    lowers to a log2(len)-trip while loop whose body round-trips the full
+    tensor each iteration (~7x the HBM traffic on activation-sized inputs,
+    measured in EXPERIMENTS.md §Perf); the compare-count is a single fusion
+    with a [..., len(mids)] intermediate. (FloatSD8's ``core.floatsd`` and
+    the Pallas kernels use ``core.floatsd.select_lut`` instead, a select
+    chain with no such intermediate and no table gather.)"""
+    return jnp.sum(
+        (n[..., None] > mids[(None,) * n.ndim]).astype(jnp.int32), axis=-1
+    )
 
 
 def _num_groups(k: int) -> int:

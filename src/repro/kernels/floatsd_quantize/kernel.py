@@ -15,7 +15,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from ...core import floatsd
@@ -23,14 +22,11 @@ from .. import mosaic
 
 __all__ = ["quantize_kernel", "quantize_pallas"]
 
-_GRID = floatsd._GRID_POS.astype(np.float32)  # 65 non-negative values
-_MID = ((_GRID[1:] + _GRID[:-1]) / 2).astype(np.float32)  # 64 midpoints
-_E = floatsd._GRID_E.astype(np.int32)
-_MIDX = floatsd._GRID_MIDX.astype(np.int32)
+_GRID = floatsd._GRID_POS32  # 65 non-negative values
+_MID = floatsd._GRID_MID32  # 64 midpoints
 # per grid slot: the code for +value in the low byte, for -value in the next
-# (``core.floatsd.encode``: code = e << 5 | mantissa index, sign folded into
-# the index as 30 - idx)
-_CODES = ((_E << 5) | _MIDX) | (((_E << 5) | (30 - _MIDX)) << 8)
+# (the pairs ``core.floatsd.encode`` selects from)
+_CODES = floatsd._GRID_CODES
 
 
 def quantize_kernel(x_ref, inv_scale_ref, out_ref):

@@ -6,7 +6,8 @@ compiling for a described v5e chip, ``tests/test_tpu_compile.py``):
 
   * **no 1-D table gathers** (``Only 2D gather is supported``): a LUT
     lookup inside a kernel is a select chain over compile-time constants
-    (:func:`select_lut`) or arithmetic on the code bits;
+    (:func:`select_lut`, defined in ``core.floatsd``, whose XLA path uses
+    it too, and re-exported here) or arithmetic on the code bits;
   * **no f16 vectors** (``Invalid vector type for load``; f32->f16 casts
     fail to legalize): f16 operands cross the kernel boundary as f32
     (:func:`io_dtype`, :func:`widen`; both directions are exact), and a
@@ -23,7 +24,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from ..core.floatsd import select_lut
 
 __all__ = [
     "io_dtype", "widen", "pow2", "select_lut", "mxu_dot", "vmem_scratch",
@@ -46,19 +48,6 @@ def widen(x: jax.Array) -> jax.Array:
 def pow2(k: jax.Array) -> jax.Array:
     """Exact 2^k as f32 for int32 ``k`` in [-126, 127] (callers clamp)."""
     return jax.lax.bitcast_convert_type((k + 127) << 23, jnp.float32)
-
-
-def select_lut(x: jax.Array, mids: np.ndarray, values: np.ndarray) -> jax.Array:
-    """``values[#(mids < x)]`` for ascending ``mids`` (len(values) ==
-    len(mids) + 1): the compare-count LUT lookup as a select chain over
-    compile-time constants. ``x > mids[k]`` holds for a prefix of k, so the
-    last select that fires picks exactly the counted index (NaN -> values[0],
-    as the count gives)."""
-    assert len(values) == len(mids) + 1
-    out = jnp.full(x.shape, values[0], np.asarray(values).dtype)
-    for m, v in zip(np.asarray(mids).tolist(), np.asarray(values)[1:]):
-        out = jnp.where(x > m, v, out)
-    return out
 
 
 def mxu_dot(a: jax.Array, b: jax.Array, *, trans_b: bool = False) -> jax.Array:
